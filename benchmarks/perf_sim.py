@@ -286,8 +286,7 @@ def measure(spec, skip_equivalence: bool = False, devices=None,
     rows_1 = None
     for d in DEVICE_COUNTS:
         if d > have:
-            scaling[str(d)] = {"skipped":
-                               f"only {have} logical devices in pool"}
+            scaling[str(d)] = {"skipped": f"only {have} devices in pool"}
             continue
         fn = _engine_fn("jit", lib, policy, tasksets, seeds,
                         spec["duration"], devices=d)
@@ -368,8 +367,9 @@ def main() -> None:
                     help="measure timings only (CI's measure step — its "
                          "gating sibling already ran the checks)")
     ap.add_argument("--devices", type=int, default=None,
-                    help="logical host devices for the jit engine "
-                         "(default: REPRO_DEVICES or 1); the "
+                    help="devices for the jit engine: TPU chips, or "
+                         "logical CPU devices (default: REPRO_DEVICES "
+                         "or 1); the "
                          "device_scaling rows always cover "
                          f"{DEVICE_COUNTS}")
     ap.add_argument("--out", default=str(DEFAULT_OUT),
@@ -383,8 +383,10 @@ def main() -> None:
 
     # the logical-device pool must be forced before the first jax
     # computation (XLA reads the flag once); cover the scaling rows too
-    from repro.runtime.device_config import (configure_host_devices,
+    from repro.runtime.device_config import (configure_compile_cache,
+                                             configure_host_devices,
                                              default_device_count)
+    configure_compile_cache()
     devices = default_device_count() if args.devices is None \
         else args.devices
     configure_host_devices(max(devices, max(DEVICE_COUNTS)))

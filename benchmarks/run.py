@@ -16,15 +16,20 @@ single-accelerator simulation sweeps through the vectorized batch
 backend (``core.simulator_vec``); ``--engine jit`` through the fully-
 compiled ``jax.lax.while_loop`` backend (``core.simulator_jit``,
 statistically equivalent RNG contract).  ``--devices N`` shards the
-jit engine's point axis over N logical host devices (bit-identical
-results and shared cache entries at any count — a pure throughput
-knob; see docs/performance.md).  Each engine has its own cache
-namespace, see docs/performance.md.  ``--scenario NAME`` runs the
-scenario-capable sim figures (fig8/fig9/fig10) under a declarative
-fault/demand scenario (``repro.scenarios``, e.g. ``heavy_tail`` or
-``faults@0.5``; see docs/scenarios.md) — fig13 sweeps the whole
-``faults@<x>`` family itself.  ``--smoke`` runs a 2-point sweep
-end-to-end (used by CI).
+jit engine's point axis over N devices: TPU chips on a TPU host (more
+than the host has is an error), logical host devices on the CPU.  On
+one platform the rows and cache entries are identical at any count —
+a pure throughput knob.  Across platforms only nominal-profile rows
+are: a TPU's f64 is not IEEE, so sampled and scenario jit points run
+there are cached under keys that name the platform (see
+docs/performance.md).  jit chunks run in this process, which holds the
+device; workers get the CPU only.  Each engine has its own cache
+namespace, see docs/performance.md.
+``--scenario NAME`` runs the scenario-capable sim figures (fig8/fig9/
+fig10) under a declarative fault/demand scenario (``repro.scenarios``,
+e.g. ``heavy_tail`` or ``faults@0.5``; see docs/scenarios.md) — fig13
+sweeps the whole ``faults@<x>`` family itself.  ``--smoke`` runs a
+2-point sweep end-to-end (used by CI).
 """
 from __future__ import annotations
 
@@ -74,10 +79,10 @@ def main() -> None:
                          "(vec = vectorized batch engine, jit = fully-"
                          "compiled jax.lax.while_loop backend)")
     ap.add_argument("--devices", type=int, default=None,
-                    help="logical host devices the jit engine shards "
-                         "points over (requires --engine jit; results "
-                         "and cache entries are identical at any "
-                         "count)")
+                    help="devices the jit engine shards points over: "
+                         "TPU chips, or logical CPU devices (requires "
+                         "--engine jit; on one platform results and "
+                         "cache entries are identical at any count)")
     ap.add_argument("--scenario", default=None,
                     help="declarative fault/demand scenario for the "
                          "scenario-capable sim figures (fig8/fig9/"
@@ -87,6 +92,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.devices is not None and args.engine != "jit":
         ap.error("--devices requires --engine jit")
+    from repro.runtime.device_config import configure_compile_cache
+    configure_compile_cache()
     if args.scenario is not None:      # fail loudly before any campaign
         from repro.scenarios import get_scenario
         try:

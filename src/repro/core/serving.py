@@ -179,10 +179,7 @@ class MESCServer:
 
     def _restore(self, r: Request):
         self.arena.acquire(self.lane, r.rid)
-        if r.cache is None:
-            _, r.cache = self._prefill(
-                self.params, {"tokens": jnp.asarray(r.prompt[None])})
-        elif not r.resident:
+        if r.cache is not None and not r.resident:
             r.cache = jax.device_put(r.cache)             # step_wise_mvin
             self._charge(self._cs_restore_s)
         r.resident = True
@@ -247,8 +244,10 @@ class MESCServer:
 
     # -- the serve loop -----------------------------------------------------
     def step(self) -> Optional[int]:
-        """One scheduler invocation + one instruction (decode step).
-        Returns the rid that ran, or None if idle."""
+        """One scheduler invocation + one instruction, which emits one
+        token: the prompt's prefill for a request's first token, one
+        decode step for every later one.  Returns the rid that ran, or
+        None if idle."""
         self._mode_tick()
         r = self._pick()
         # non-preemptive baseline: a started request owns the accelerator
@@ -269,10 +268,13 @@ class MESCServer:
         if r.started_at is None:
             r.started_at = self.clock()
         t0 = self.clock()
-        last = (r.generated[-1] if r.generated else int(r.prompt[-1]))
-        logits, r.cache = self._decode(self.params,
-                                       jnp.asarray([last], jnp.int32),
-                                       r.cache)
+        if r.cache is None:                # last-position prefill logits
+            logits, r.cache = self._prefill(
+                self.params, {"tokens": jnp.asarray(r.prompt[None])})
+        else:
+            logits, r.cache = self._decode(
+                self.params, jnp.asarray([r.generated[-1]], jnp.int32),
+                r.cache)
         tok = int(jnp.argmax(logits[0]))
         r.generated.append(tok)
         r.exec_s += self.clock() - t0

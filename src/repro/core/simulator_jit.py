@@ -134,9 +134,9 @@ Implementation notes
     single-device engine at any device count (gated in CI at
     ``REPRO_DEVICES`` 2 and 4).  The carry is donated to the runner,
     so the dominant buffers are reused in place.
-  * Everything runs in float64/int64 under ``jax.experimental
-    .enable_x64`` (scoped, not process-global): event times must not
-    round-trip through float32.
+  * Everything runs in float64/int64 under ``jax.enable_x64(True)``
+    (scoped, not process-global): event times must not round-trip
+    through float32.
 
 JAX is an optional dependency of this module: importing it (and
 ``core.simulator_vec``) works without JAX installed; selecting the
@@ -1024,7 +1024,7 @@ def _compiled_run(use_banks: bool, drop_lo: bool, preempt: str,
     logical-device mesh: per-point tables and the whole carry shard
     along the point axis, the global program tables and scalars
     replicate, and — because simulation points are independent — the
-    mapped body needs no collectives (``check_rep=False``: there is no
+    mapped body needs no collectives (``check_vma=False``: there is no
     replicated output for shard_map to prove anything about).  Each
     device's while_loop halts when its own point-shard quiesces, so a
     fast shard does not wait for a slow one's extra steps.  The carry
@@ -1034,7 +1034,6 @@ def _compiled_run(use_banks: bool, drop_lo: bool, preempt: str,
                      scenario)
     if devices == 1:
         return jax.jit(run, donate_argnums=(2,))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.runtime.sharding import logical_device_mesh
@@ -1055,9 +1054,9 @@ def _compiled_run(use_banks: bool, drop_lo: bool, preempt: str,
         return out
 
     return jax.jit(
-        shard_map(_dev_body, mesh=mesh,
-                  in_specs=(tb_specs, sc_specs, carry_specs),
-                  out_specs=carry_specs, check_rep=False),
+        jax.shard_map(_dev_body, mesh=mesh,
+                      in_specs=(tb_specs, sc_specs, carry_specs),
+                      out_specs=carry_specs, check_vma=False),
         donate_argnums=(2,))
 
 
@@ -1169,11 +1168,10 @@ def _run_once(b: _VecBatch, policy: Policy, seeds: Sequence[int],
     run = _compiled_run(policy.use_banks, policy.drop_lo_in_hi,
                         policy.preemption, nominal, _PRUNE_STALE,
                         scenario, devices)
-    from jax.experimental import enable_x64
     max_steps = _max_steps(b, duration)
     # event times are float64; everything (array upload included) must
     # happen under x64 or XLA would round-trip them through float32
-    with enable_x64():
+    with jax.enable_x64(True):
         tb = _tables(b, seeds)
         sc = {"t_sr": jnp.float64(policy.t_sr),
               "overrun_prob": jnp.float64(overrun_prob),
@@ -1367,9 +1365,8 @@ def lockstep_kernel_count(tasksets: Sequence[List[TaskParams]],
     run = _compiled_run(policy.use_banks, policy.drop_lo_in_hi,
                         policy.preemption, nominal, _PRUNE_STALE,
                         loop_scen)
-    from jax.experimental import enable_x64
     max_steps = _max_steps(b, duration)
-    with enable_x64():
+    with jax.enable_x64(True):
         tb = _tables(b, seeds)
         sc = {"t_sr": jnp.float64(policy.t_sr),
               "overrun_prob": jnp.float64(overrun_prob),

@@ -22,22 +22,9 @@ import tempfile
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
+from repro.runtime.device_config import _env_path
+
 DEFAULT_CACHE_DIR = "results/campaigns"
-
-
-def _env_path(name: str, default: str) -> Path:
-    """Read a directory-path env override, rejecting junk loudly.
-
-    A blank-but-set variable almost always means a broken launch
-    script; failing at startup beats silently caching into ``.``.
-    """
-    raw = os.environ.get(name)
-    if raw is None:
-        return Path(default)
-    if not raw.strip():
-        raise ValueError(
-            f"{name} is set but blank; set a directory path or unset it")
-    return Path(raw)
 
 
 def default_cache_dir() -> Path:

@@ -9,7 +9,10 @@ point), so the runner simply:
      up in the :class:`~repro.experiments.cache.ResultCache`;
   2. executes only the misses — serially for tiny batches, otherwise on
      a ``ProcessPoolExecutor`` (workers default to the CPU count, or
-     the ``REPRO_WORKERS`` env var);
+     the ``REPRO_WORKERS`` env var).  ``engine="jit"`` chunks always run
+     in the calling process, the one that may hold the accelerator;
+     pool workers are spawned with JAX pinned to the CPU, so no worker
+     ever opens the device;
   3. writes each fresh row back to the cache and a campaign manifest
      under the sweep's spec hash.
 
@@ -37,7 +40,9 @@ from __future__ import annotations
 
 import functools
 import importlib
+import multiprocessing
 import os
+import sys
 from concurrent.futures import ProcessPoolExecutor, as_completed
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple, Union
@@ -180,6 +185,16 @@ def _execute_chunk(payloads: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     return rows  # type: ignore[return-value]
 
 
+def _pool_worker_init() -> None:
+    """Pool workers run NumPy points.  A chip belongs to one process,
+    the campaign's own, so any incidental JAX use in a worker (the
+    virtual serving path's eager transfers) is pinned to the CPU."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:     # imported with the main module: too late for
+        jax.config.update("jax_platforms", "cpu")    # the env variable
+
+
 def _echo_point(**kwargs) -> Dict[str, Any]:
     """Trivial FuncSweep target used by the engine's own tests."""
     return {"echo": True, "pid": os.getpid(), **kwargs}
@@ -220,19 +235,32 @@ class Campaign:
         # vec/jit-engine sim points take the chunked batch-execution
         # path: whole cache-miss chunks go to simulate_vbatch instead
         # of one point per task (each point still cached individually)
-        vec_sel = [k for k, i in enumerate(todo)
-                   if isinstance(points[i], SimPoint)
-                   and points[i].engine in ("vec", "jit")]
-        vec_set = set(vec_sel)
-        other_sel = [k for k in range(len(todo)) if k not in vec_set]
-        if len(payloads) <= 1 or self.workers <= 1:
+        batch = [points[i].engine if isinstance(points[i], SimPoint)
+                 and points[i].engine in ("vec", "jit") else None
+                 for i in todo]
+        vec_sel = [k for k, e in enumerate(batch) if e == "vec"]
+        other_sel = [k for k, e in enumerate(batch) if e is None]
+        # jit chunks run here, never in a worker: the device is held by
+        # this process.  Each chunk is stored as it finishes, like the
+        # pool's below.
+        jit_sel = [k for k, e in enumerate(batch) if e == "jit"]
+        for lo in range(0, len(jit_sel), VEC_CHUNK):
+            sel = jit_sel[lo:lo + VEC_CHUNK]
+            self._drain([todo[k] for k in sel], keys, rows,
+                        _execute_chunk([payloads[k] for k in sel]))
+        if len(vec_sel) + len(other_sel) <= 1 or self.workers <= 1:
             if vec_sel:
                 out = _execute_chunk([payloads[k] for k in vec_sel])
                 self._drain([todo[k] for k in vec_sel], keys, rows, out)
             fresh = (_execute(payloads[k]) for k in other_sel)
             self._drain([todo[k] for k in other_sel], keys, rows, fresh)
         else:
-            with ProcessPoolExecutor(max_workers=self.workers) as ex:
+            # spawn, not fork: a forked child would inherit this
+            # process's live device client
+            with ProcessPoolExecutor(
+                    max_workers=self.workers,
+                    mp_context=multiprocessing.get_context("spawn"),
+                    initializer=_pool_worker_init) as ex:
                 futures = {}
                 if vec_sel:
                     per = max(1, min(VEC_CHUNK,

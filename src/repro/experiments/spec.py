@@ -32,8 +32,9 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 from repro.core.scheduler import Policy
 from repro.core.simulator import DEMAND_PROFILES, SIM_SEMANTICS_VERSION
-# both engine salts live in (jax-free) simulator_vec: hashing a jit
-# point must not import JAX into every campaign worker
+# both engine salts live in (jax-free) simulator_vec: importing this
+# module must not import JAX into every campaign worker (only a jit
+# point's key asks JAX for its platform, in the process that runs it)
 from repro.core.simulator_vec import (JIT_SIM_SEMANTICS_VERSION,
                                       VEC_SIM_SEMANTICS_VERSION)
 from repro.core.taskgen import point_seed
@@ -135,6 +136,18 @@ class SimPoint:
         # cache entries (pinned by tests/test_campaign_cache.py)
         d = self.to_dict()
         d.pop("devices", None)
+        # a TPU's f64 keeps fewer than 53 significant bits, so jit rows
+        # with in-loop draws or scenario arithmetic differ between a
+        # TPU and the CPU: off the CPU such points carry their platform
+        # (CPU keys are unchanged).  Nominal points hold integer cycle
+        # times, which a TPU's f64 still holds exactly, and share one
+        # key.
+        if self.engine == "jit" and (self.demand_profile != "nominal"
+                                     or self.scenario is not None):
+            from repro.runtime.device_config import jit_platform
+            platform = jit_platform(self.devices)
+            if platform != "cpu":
+                d["jit_platform"] = platform
         return canonical_hash(d)
 
     def policy_obj(self) -> Policy:

@@ -1,10 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute via ``interpret=True`` —
-bit-exact kernel-body semantics in Python — and the jnp reference path is
-used by the models by default.  On TPU backends the kernels compile natively
-(interpret=False) and are the drop-in hot-spot replacements measured in
-EXPERIMENTS.md SSPerf.
+On CPU the kernels execute via ``interpret=True`` — bit-exact kernel-body
+semantics in Python.  On TPU backends they compile natively
+(``interpret=False``); ``tests/test_tpu_compile.py`` compiles each one for a
+v5e.  The models use their jnp paths: no model calls these wrappers yet.
 """
 from __future__ import annotations
 

@@ -23,16 +23,16 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, y_ref, state_ref, *, bs: int):
 
     @pl.when(si == 0)
     def _init():
-        state_ref[...] = h0_ref[...]
+        state_ref[...] = h0_ref[0]
 
-    a = a_ref[0]                       # (bs, bd)
-    b = b_ref[0]
     h = state_ref[...]                 # (1, bd)
 
-    def step(t, carry):
-        h = carry
-        h = a[t][None] * h + b[t][None]
-        y_ref[0, t] = h[0]
+    def step(t, h):
+        # row t read from the refs: Mosaic lowers a dynamic ref slice,
+        # not a dynamic index into an already-loaded block
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        y_ref[0, row, :] = h
         return h
 
     h = jax.lax.fori_loop(0, bs, step, h)
@@ -55,8 +55,10 @@ def rglru_scan_tpu(a, b, h0, *, block_s: int = 256, block_d: int = 256,
             pl.BlockSpec((1, bs, bd),
                          lambda bd_i, si: (bd_i // (D // bd), si,
                                            bd_i % (D // bd))),
-            pl.BlockSpec((1, bd),
-                         lambda bd_i, si: (bd_i // (D // bd),
+            # h0 as (B, 1, D): a (1, bd) block of (B, D) breaks the
+            # TPU's (8, 128) tiling rule whenever B > 1
+            pl.BlockSpec((1, 1, bd),
+                         lambda bd_i, si: (bd_i // (D // bd), 0,
                                            bd_i % (D // bd))),
         ],
         out_specs=pl.BlockSpec((1, bs, bd),
@@ -65,4 +67,4 @@ def rglru_scan_tpu(a, b, h0, *, block_s: int = 256, block_d: int = 256,
         out_shape=jax.ShapeDtypeStruct((B, S, D), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
         interpret=interpret,
-    )(a, b, h0)
+    )(a, b, h0[:, None, :])

@@ -87,7 +87,7 @@ def systolic_gemm(a, b, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=out_sds,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_DIM_SEMANTICS),
         cost_estimate=_gemm_cost(M, K, N, a.dtype, b.dtype, out_sds.dtype),
         interpret=interpret,
@@ -144,7 +144,7 @@ def gemm_partial(a, b, acc, k_begin: int, k_end: int, *,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=out_sds,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_DIM_SEMANTICS),
         cost_estimate=cost,
         interpret=interpret,

@@ -17,6 +17,12 @@ thing on the deterministic virtual clock + service model (no model
 weights, byte-reproducible — the fig12 path); without it the real
 model serves the trace in wall-clock time.
 
+The real model runs with the bf16 runtime config on a TPU and in f32
+elsewhere; ``--arch`` defaults to TinyLlama 1.1B at full width on a TPU
+and to its smoke reduction elsewhere.  Weights are random, from a fixed
+seed.
+
+  PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b-smoke
   PYTHONPATH=src python -m repro.launch.serve --lanes 2 --heuristic crit_aware
   PYTHONPATH=src python -m repro.launch.serve --arrivals poisson --virtual
@@ -27,63 +33,81 @@ from __future__ import annotations
 import argparse
 import time
 from collections import deque
+from typing import Optional
 
 import numpy as np
 
 from repro.core.scheduler import Policy
 from repro.core.serving import MESCServer, MultiLaneServer, Request
 from repro.core.task import Crit
+from repro.runtime.device_config import configure_compile_cache
 from repro.serving import (FrontDoor, PROCESS_KINDS, build_workload,
                            make_process, run_virtual_serving, slo_summary)
 
 
-def _load_model(arch: str):
-    """Real-model setup, imported lazily so ``--virtual`` runs stay
-    free of jax/model-weight start-up cost."""
+def load_model(arch: Optional[str]):
+    """Real-model setup: ``(cfg, params, rc)``.  Imported lazily so
+    ``--virtual`` runs stay free of jax/model-weight start-up cost."""
     import jax
     from repro.configs import get_config
     from repro.models import lm
-    from repro.models.common import CPU_RC
-    cfg = get_config(arch)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0), CPU_RC)
-    return cfg, params
+    from repro.models.common import CPU_RC, DEFAULT_RC
+    on_tpu = jax.default_backend() == "tpu"
+    rc = DEFAULT_RC if on_tpu else CPU_RC
+    cfg = get_config(arch or ("tinyllama-1.1b" if on_tpu
+                              else "tinyllama-1.1b-smoke"))
+    params = lm.init_params(cfg, jax.random.PRNGKey(0), rc)
+    return cfg, params, rc
 
 
 def make_requests(cfg, rng, n_lo: int = 4, n_hi: int = 2,
-                  lo_len: int = 24, hi_len: int = 6):
+                  lo_len: int = 24, hi_len: int = 6,
+                  prompt_len: int = 8):
     reqs = []
     rid = 0
     for _ in range(n_lo):
         reqs.append(Request(rid=rid, priority=10 + rid,
-                            prompt=rng.integers(0, cfg.vocab, 8,
+                            prompt=rng.integers(0, cfg.vocab, prompt_len,
                                                 dtype=np.int32),
                             max_new_tokens=lo_len, crit=Crit.LO))
         rid += 1
     for _ in range(n_hi):
         reqs.append(Request(rid=rid, priority=rid - n_lo,
-                            prompt=rng.integers(0, cfg.vocab, 8,
+                            prompt=rng.integers(0, cfg.vocab, prompt_len,
                                                 dtype=np.int32),
                             max_new_tokens=hi_len, crit=Crit.HI))
         rid += 1
     return reqs
 
 
-def run(cfg, params, policy, reqs, hi_delay_steps: int = 3,
-        lanes: int = 1, heuristic: str = "crit_aware"):
-    """LO requests submitted first; HI requests arrive mid-flight."""
+def _make_server(cfg, params, policy, *, rc, max_len: int, lanes: int,
+                 heuristic: str):
     if lanes > 1:
-        srv = MultiLaneServer(cfg, params, policy=policy, max_len=64,
-                              n_lanes=lanes, heuristic=heuristic)
-    else:
-        srv = MESCServer(cfg, params, policy=policy, max_len=64)
-    # warmup: compile prefill+decode outside the measured window
-    warm = Request(rid=-1, priority=99,
-                   prompt=np.zeros(8, np.int32), max_new_tokens=2,
-                   crit=Crit.LO)
-    srv.submit(warm)
+        return MultiLaneServer(cfg, params, policy=policy, rc=rc,
+                               max_len=max_len, n_lanes=lanes,
+                               heuristic=heuristic)
+    return MESCServer(cfg, params, policy=policy, rc=rc, max_len=max_len)
+
+
+def _warm(srv, prompt_lens) -> None:
+    """Compile prefill (one per prompt length) and decode outside the
+    measured window, then forget the warm-up requests."""
+    for i, n in enumerate(sorted(set(prompt_lens))):
+        srv.submit(Request(rid=-1 - i, priority=99,
+                           prompt=np.zeros(n, np.int32), max_new_tokens=2,
+                           crit=Crit.LO))
     srv.run()
     for ln in getattr(srv, "lanes", [srv]):
         ln.requests.clear()
+
+
+def run(cfg, params, policy, reqs, hi_delay_steps: int = 3,
+        lanes: int = 1, heuristic: str = "crit_aware", *, rc,
+        max_len: int):
+    """LO requests submitted first; HI requests arrive mid-flight."""
+    srv = _make_server(cfg, params, policy, rc=rc, max_len=max_len,
+                       lanes=lanes, heuristic=heuristic)
+    _warm(srv, [len(r.prompt) for r in reqs])
     lo = [r for r in reqs if r.crit == Crit.LO]
     hi = [r for r in reqs if r.crit == Crit.HI]
     for r in lo:
@@ -111,22 +135,14 @@ def summarize(name, reqs):
     return out
 
 
-def run_traffic_real(cfg, params, policy, workload, *, lanes: int = 1,
-                     heuristic: str = "crit_aware",
+def run_traffic_real(cfg, params, policy, workload, *, rc, max_len: int,
+                     lanes: int = 1, heuristic: str = "crit_aware",
                      max_live_lo=None, prompt_len: int = 8):
     """Open-loop wall-clock drive: the real model serves a CRN arrival
     realization in real time through the admission front door."""
-    if lanes > 1:
-        srv = MultiLaneServer(cfg, params, policy=policy, max_len=64,
-                              n_lanes=lanes, heuristic=heuristic)
-    else:
-        srv = MESCServer(cfg, params, policy=policy, max_len=64)
-    warm = Request(rid=-1, priority=99, prompt=np.zeros(8, np.int32),
-                   max_new_tokens=2, crit=Crit.LO)
-    srv.submit(warm)
-    srv.run()
-    for ln in getattr(srv, "lanes", [srv]):
-        ln.requests.clear()
+    srv = _make_server(cfg, params, policy, rc=rc, max_len=max_len,
+                       lanes=lanes, heuristic=heuristic)
+    _warm(srv, [prompt_len])
 
     rng = np.random.default_rng(0)
     t0 = time.monotonic()
@@ -181,7 +197,7 @@ def main_traffic(args):
           f"lanes={args.lanes}, n_lo={args.n_lo}, n_hi={args.n_hi}, "
           f"lo_rate={args.rate}/s, hi_rate={args.hi_rate}/s)")
     if not args.virtual:
-        cfg, params = _load_model(args.arch)
+        cfg, params, rc = load_model(args.arch)
     rows = {}
     for name, policy in (("mesc", Policy.mesc()),
                          ("np", Policy.non_preemptive())):
@@ -192,7 +208,8 @@ def main_traffic(args):
                 max_live_lo=args.max_live_lo)
         else:
             reqs = run_traffic_real(
-                cfg, params, policy, workload, lanes=args.lanes,
+                cfg, params, policy, workload, rc=rc,
+                max_len=args.max_len, lanes=args.lanes,
                 heuristic=args.heuristic, max_live_lo=args.max_live_lo)
         rows[name] = slo_summary(reqs.values(),
                                  hi_deadline_s=args.hi_deadline)
@@ -205,7 +222,12 @@ def main_traffic(args):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="tinyllama-1.1b-smoke")
+    ap.add_argument("--arch", default=None,
+                    help="model config (default: tinyllama-1.1b on a "
+                         "TPU, tinyllama-1.1b-smoke elsewhere)")
+    ap.add_argument("--max-len", type=int, default=512,
+                    help="KV-cache length per request: prompt plus "
+                         "generated tokens")
     ap.add_argument("--lanes", type=int, default=1,
                     help="virtual accelerator dispatch lanes (partitioned "
                          "MESC when > 1)")
@@ -239,13 +261,15 @@ def main():
     args = ap.parse_args()
     if args.arrivals == "trace" and not args.trace:
         ap.error("--arrivals trace requires --trace PATH")
+    configure_compile_cache()
     if args.arrivals != "batch":
         main_traffic(args)
         return
 
-    cfg, params = _load_model(args.arch)
+    cfg, params, rc = load_model(args.arch)
     rng = np.random.default_rng(0)
-    lane_kw = dict(lanes=args.lanes, heuristic=args.heuristic)
+    lane_kw = dict(lanes=args.lanes, heuristic=args.heuristic, rc=rc,
+                   max_len=args.max_len)
     print(f"MESC (instruction-level preemption, lanes={args.lanes}):")
     mesc = summarize("mesc", run(cfg, params, Policy.mesc(),
                                  make_requests(cfg, rng), **lane_kw))

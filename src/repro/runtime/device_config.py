@@ -1,12 +1,18 @@
-"""Host/device environment configuration for the sharded jit engine.
+"""Host/device environment configuration for the device paths.
 
-XLA exposes one CPU device per process by default; the sharded jit
-dispatcher (``core.simulator_jit``) spreads independent simulation
-points over *logical* host devices carved out of the same CPU via
-``--xla_force_host_platform_device_count`` — each logical device runs
-its own copy of the compiled lockstep ``while_loop`` on a shard of the
-point axis, so XLA:CPU's per-kernel dispatch queues proceed in
-parallel instead of serializing behind one device queue.
+The sharded jit dispatcher (``core.simulator_jit``) spreads
+independent simulation points over a 1-D mesh of devices.  On a TPU
+host those are the chips themselves: a ``--devices N`` request larger
+than the live TPU pool is an error (:func:`resolve_device_count`).  On
+the CPU backend, which exposes one device per process by default, the
+devices are *logical* host devices carved out of the same CPU via
+``--xla_force_host_platform_device_count``: each runs its own copy of
+the compiled lockstep ``while_loop`` on a shard of the point axis, so
+XLA:CPU's per-kernel dispatch queues proceed in parallel.  That is how
+the test suite exercises the sharded path without a chip.
+
+:func:`configure_compile_cache` places JAX's persistent compilation
+cache; every entry point that compiles for the device calls it first.
 
 Everything here is env/flag plumbing and therefore importable without
 JAX (JAX is only touched lazily, to ask whether its backends are
@@ -26,6 +32,7 @@ import os
 import re
 import sys
 import warnings
+from pathlib import Path
 from typing import Optional
 
 # logical host devices are threads multiplexed onto the same silicon:
@@ -42,6 +49,11 @@ _GPU_XLA_FLAGS = (
     "--xla_gpu_enable_highest_priority_async_stream=true")
 
 _DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
+
+# the compile cache's home when JAX_COMPILATION_CACHE_DIR is unset: a
+# fixed path (the directory is part of the cache key, so one that moved
+# per run would never hit), git-ignored
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _env_int(name: str, default: int, minimum: int = 1,
@@ -69,6 +81,39 @@ def _env_int(name: str, default: int, minimum: int = 1,
             f"{name}={raw!r} exceeds the maximum of {maximum} logical "
             f"devices; fix or unset {name}")
     return val
+
+
+def _env_path(name: str, default=None) -> Optional[Path]:
+    """Read a directory-path env override, rejecting junk loudly:
+    ``default`` (as a ``Path``, or ``None``) when unset.
+
+    A blank-but-set variable almost always means a broken launch
+    script; failing at startup beats silently writing into ``.``.
+    """
+    raw = os.environ.get(name)
+    if raw is None:
+        return None if default is None else Path(default)
+    if not raw.strip():
+        raise ValueError(
+            f"{name} is set but blank; set a directory path or unset it")
+    return Path(raw)
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads the variable
+    itself and nothing is set here.  Otherwise the cache goes to
+    :data:`DEFAULT_COMPILE_CACHE` (``<repo>/.jax_cache``).  Call it
+    before the first compilation.
+    """
+    env = _env_path("JAX_COMPILATION_CACHE_DIR")
+    if env is not None:
+        return str(env)
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
 
 
 def default_device_count() -> int:
@@ -172,10 +217,12 @@ def resolve_device_count(requested: Optional[int] = None) -> int:
 
     ``requested=None`` means the ``REPRO_DEVICES`` default.  For counts
     above 1 this forces the logical-device flag when the backend is not
-    yet live; if the backend already is (or the platform offers fewer
-    devices), the count is clamped to what exists, with a loud warning —
-    results are bit-identical at any device count, so clamping is a
-    performance event, not a correctness one.
+    yet live.  On an accelerator a request beyond the live pool raises
+    ``ValueError``: the run would otherwise measure fewer chips than it
+    names.  On the CPU backend the count is clamped to the logical
+    devices that exist, with a loud warning — results are bit-identical
+    at any device count, so there clamping is a performance event, not
+    a correctness one.
     """
     want = default_device_count() if requested is None else int(requested)
     if want < 1 or want > MAX_LOGICAL_DEVICES:
@@ -189,6 +236,10 @@ def resolve_device_count(requested: Optional[int] = None) -> int:
         configure_host_devices(max(want, default_device_count()))
     import jax
     have = jax.local_device_count()
+    if have < want and jax.default_backend() != "cpu":
+        raise ValueError(
+            f"devices={want} requested but this host has {have} "
+            f"{jax.default_backend()} device(s)")
     if have < want:
         warnings.warn(
             f"requested {want} logical devices but this process has "
@@ -198,3 +249,12 @@ def resolve_device_count(requested: Optional[int] = None) -> int:
             RuntimeWarning, stacklevel=2)
         return have
     return want
+
+
+def jit_platform(devices: Optional[int] = None) -> str:
+    """The platform the jit engine runs ``devices`` devices on in this
+    process (``cpu``, ``tpu``, ...).  Sizes the device pool first, as
+    the engine would, so asking never locks a smaller pool in."""
+    resolve_device_count(devices)
+    import jax
+    return jax.default_backend()

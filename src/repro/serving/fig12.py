@@ -29,7 +29,10 @@ from repro.serving.frontend import ServiceModelSpec, run_virtual_serving
 from repro.serving.slo import slo_summary
 from repro.serving.traffic import Poisson, build_workload, make_process
 
-SERVING_SEMANTICS_VERSION = 1
+# v2 = the prefill emits a request's first token (v1 decoded the
+# prompt's last token a second time), so every request takes one
+# decode step fewer.
+SERVING_SEMANTICS_VERSION = 2
 
 POLICIES = {
     "mesc": Policy.mesc,

@@ -66,12 +66,13 @@ class VirtualModel:
     """Deterministic (decode, prefill) pair for one dispatch lane.
 
     Each call advances the lane's :class:`VirtualClock` by a service
-    time drawn from the counter-based CRN — decode step ``k`` of
-    request ``rid`` costs ``decode_mean_s * (1 +- jitter)`` with the
-    uniform jitter keyed ``(seed, 'svc_decode', rid * stride + k)``,
-    prefill ``prefill_mean_s`` likewise.  The "KV cache" is a plain
-    dict carrying (rid, pos, k); generated tokens are CRN draws too,
-    so the full request transcript is byte-reproducible."""
+    time drawn from the counter-based CRN — prefill costs
+    ``prefill_mean_s * (1 +- jitter)`` with the uniform jitter keyed
+    ``(seed, 'svc_prefill', rid)`` and emits token ``k = 0``; decode
+    step ``k >= 1`` of request ``rid`` costs ``decode_mean_s`` likewise,
+    keyed ``(seed, 'svc_decode', rid * stride + k)``.  The "KV cache" is
+    a plain dict carrying (rid, pos, k); generated tokens are CRN draws
+    too, so the full request transcript is byte-reproducible."""
 
     def __init__(self, clock: VirtualClock, *, seed: int,
                  decode_mean_s: float = 0.010,
@@ -92,13 +93,20 @@ class VirtualModel:
         u = float(crn_u01(self.seed, stream, idx))
         return mean * (1.0 + self.jitter * (2.0 * u - 1.0))
 
+    def _token(self, idx: int) -> np.ndarray:
+        """One-hot logits of the CRN token drawn at ``idx``."""
+        logits = np.zeros((1, self.vocab), np.float32)
+        logits[0, int(crn_u01(self.seed, "tok", idx) * self.vocab)] = 1.0
+        return logits
+
     def prefill(self, params, batch):
         del params
         tokens = np.asarray(batch["tokens"])
         rid = int(tokens[0, 0])
         self.clock.advance(self._service("svc_prefill", rid,
                                          self.prefill_mean_s))
-        return None, {"rid": rid, "pos": int(tokens.shape[1]), "k": 0}
+        return self._token(rid * _RID_STRIDE), \
+            {"rid": rid, "pos": int(tokens.shape[1]), "k": 1}
 
     def decode(self, params, tok, cache):
         del params, tok                     # service keyed by (rid, k)
@@ -106,11 +114,8 @@ class VirtualModel:
         idx = rid * _RID_STRIDE + k
         self.clock.advance(self._service("svc_decode", idx,
                                          self.decode_mean_s))
-        tok_out = int(crn_u01(self.seed, "tok", idx) * self.vocab)
-        logits = np.zeros((1, self.vocab), np.float32)
-        logits[0, tok_out] = 1.0
-        return logits, {"rid": rid, "pos": int(cache["pos"]) + 1,
-                        "k": k + 1}
+        return self._token(idx), {"rid": rid, "pos": int(cache["pos"]) + 1,
+                                  "k": k + 1}
 
     @property
     def jit_fns(self):
@@ -336,6 +341,7 @@ class ServiceModelSpec:
 
     def lane_capacity_rps(self, mean_tokens: float) -> float:
         """Requests/s one lane sustains at ``mean_tokens`` per request
-        (the saturation anchor fig12's offered-load axis scales on)."""
+        (the saturation anchor fig12's offered-load axis scales on):
+        the prefill emits the first token, one decode step each other."""
         return 1.0 / (self.prefill_mean_s
-                      + mean_tokens * self.decode_mean_s)
+                      + (mean_tokens - 1) * self.decode_mean_s)

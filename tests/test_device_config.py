@@ -11,12 +11,15 @@ here are exercised against the real initialized state, and the
 pre-init flag-rewriting paths via a monkeypatched ``jax_initialized``.
 """
 import os
+from pathlib import Path
 
 import jax
 import pytest
 
 from repro.runtime import device_config as dc
-from repro.runtime.device_config import (MAX_LOGICAL_DEVICES, _env_int,
+from repro.runtime.device_config import (DEFAULT_COMPILE_CACHE,
+                                         MAX_LOGICAL_DEVICES, _env_int,
+                                         configure_compile_cache,
                                          configure_host_devices,
                                          default_device_count,
                                          jax_initialized,
@@ -165,6 +168,15 @@ class TestResolveDeviceCount:
         with pytest.warns(RuntimeWarning, match=f"running on {have}"):
             assert resolve_device_count(want) == have
 
+    def test_oversized_request_on_an_accelerator_raises(self, monkeypatch):
+        # chips are not clamped: a run must not measure fewer than it names
+        _ensure_backend()
+        have = jax.local_device_count()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match=f"has {have} tpu device"):
+            resolve_device_count(have + 1)
+        assert resolve_device_count(have) == have
+
     def test_none_reads_env_default(self, monkeypatch):
         # post-init on purpose: pre-init this would legitimately
         # re-force the pool, shrinking it for the rest of the suite
@@ -173,3 +185,34 @@ class TestResolveDeviceCount:
         assert resolve_device_count(None) == 3
         monkeypatch.delenv("REPRO_DEVICES")
         assert resolve_device_count(None) == 1
+
+
+class TestCompileCache:
+    @pytest.fixture
+    def restore_cache_dir(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_variable_is_left_to_jax(self, monkeypatch, tmp_path,
+                                         restore_cache_dir):
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_blank_variable_is_rejected(self, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", " ")
+        with pytest.raises(ValueError, match="set but blank"):
+            configure_compile_cache()
+
+    def test_default_is_the_fixed_ignored_repo_path(self, monkeypatch,
+                                                    restore_cache_dir):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = Path(__file__).resolve().parents[1]
+        assert DEFAULT_COMPILE_CACHE == root / ".jax_cache"
+        assert configure_compile_cache() == str(DEFAULT_COMPILE_CACHE)
+        assert jax.config.jax_compilation_cache_dir == \
+            str(DEFAULT_COMPILE_CACHE)
+        assert ".jax_cache/" in \
+            (root / ".gitignore").read_text().splitlines()

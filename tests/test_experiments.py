@@ -183,3 +183,71 @@ class TestAggregation:
         cells = group_rows(rows, "u")
         assert frac(cells[(0.5,)], "success_all") == 0.5
         assert frac(cells[(0.9,)], "success_all") == 0.0
+
+
+class TestDeviceOwnership:
+    """Only the campaign's own process may hold the accelerator."""
+
+    def test_jit_chunks_never_reach_the_pool(self, tmp_path, monkeypatch):
+        from repro.experiments import runner
+
+        class NoPool:
+            def __init__(self, *a, **kw):
+                raise AssertionError("jit chunk sent to a worker pool")
+        monkeypatch.setattr(runner, "ProcessPoolExecutor", NoPool)
+        sweep = tiny_sweep(n_sets=2, engine="jit", duration=2e6)
+        rows = Campaign(sweep, use_cache=False, workers=4).collect()
+        assert len(rows) == 2
+
+    def test_killed_jit_campaign_keeps_finished_chunks(self, tmp_path,
+                                                       monkeypatch):
+        from repro.experiments import runner
+        from repro.experiments.cache import ResultCache
+        run_chunk = runner._execute_chunk
+        calls = []
+
+        class Killed(Exception):
+            pass
+
+        def die_after_first(payloads):
+            if calls:
+                raise Killed
+            calls.append(len(payloads))
+            return run_chunk(payloads)
+        monkeypatch.setattr(runner, "VEC_CHUNK", 1)
+        monkeypatch.setattr(runner, "_execute_chunk", die_after_first)
+        sweep = tiny_sweep(n_sets=2, engine="jit", duration=2e6)
+        with pytest.raises(Killed):
+            Campaign(sweep, cache_dir=tmp_path).run()
+        cache = ResultCache(tmp_path)
+        first, second = sweep.points()
+        assert calls == [1]
+        assert cache.get(first.key()) is not None
+        assert cache.get(second.key()) is None
+
+    @pytest.mark.parametrize("profile,scenario,salted", [
+        ("sampled", None, True), ("nominal", "heavy_tail", True),
+        ("nominal", None, False)])
+    def test_off_cpu_jit_keys_name_the_platform(self, monkeypatch, profile,
+                                                scenario, salted):
+        """A TPU's f64 is not IEEE: jit rows with draws or scenario
+        arithmetic must not share a key with the CPU's."""
+        from repro.runtime import device_config
+        pts = [tiny_sweep(n_sets=1, engine=e, demand_profile=profile,
+                          scenario=scenario).points()[0]
+               for e in ("jit", "vec", "event")]
+        cpu = [p.key() for p in pts]
+        monkeypatch.setattr(device_config, "jit_platform",
+                            lambda devices=None: "tpu")
+        tpu = [p.key() for p in pts]
+        assert (tpu[0] != cpu[0]) == salted
+        assert tpu[1:] == cpu[1:]
+
+    def test_pool_workers_are_pinned_to_the_cpu(self, monkeypatch):
+        # not inherited: the workers pin themselves
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        fs = FuncSweep.over("env", "os:getenv",
+                            [{"key": "JAX_PLATFORMS", "default": d}
+                             for d in ("unset", None)])
+        rows = Campaign(fs, use_cache=False, workers=2).collect()
+        assert [r["result"] for r in rows] == ["cpu", "cpu"]

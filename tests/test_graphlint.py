@@ -108,7 +108,7 @@ class TestJaxprWalker:
         hist = trace.primitive_histogram(
             self._closed(f, (jnp.int32(0), jnp.float32(1.0))).jaxpr)
         assert hist.get("while") == 1
-        assert "pjit" not in hist
+        assert "jit" not in hist
         assert hist.get("mul", 0) >= 1      # inside the body sub-jaxpr
 
     def test_find_while_raises_on_whileless_graph(self):
@@ -139,9 +139,9 @@ class TestJaxprWalker:
         assert trace.dtype_summary(closed.jaxpr)["float32_ops"] >= 1
 
     def test_dtype_summary_counts_f64_demotions(self):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             closed = self._closed(lambda x: x.astype(jnp.float32),
                                   jnp.float64(0.0))
             summary = trace.dtype_summary(closed.jaxpr)
@@ -242,8 +242,10 @@ class TestBudgetManifest:
 
     def test_carry_tensor_count_tamper_fires_dtype_rule(self, tmp_path):
         path = make_tree(tmp_path)
+        carry = json.loads(path.read_text())["cases"][CANONICAL_CASE][
+            "budget"]["carry"]
         tamper(path, lambda d: d["cases"][CANONICAL_CASE]["budget"]
-               ["carry"].__setitem__("tensors", 16))
+               ["carry"].__setitem__("tensors", carry["tensors"] + 1))
         report = ir_lint(tmp_path, rules=("ir-dtype-discipline",))
         assert any("carry.tensors" in f.message
                    for f in report.findings)

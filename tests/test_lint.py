@@ -297,6 +297,20 @@ class TestSaltDrift:
         assert same == normalized_fingerprint(
             '"""other doc"""\nx = 1\ny = x + 2   # note\n')
 
+    @pytest.mark.parametrize("literal", [
+        'f"a{b!r:>{w}}"',
+        "f'''x{f\"{y}\"}\n  z{{w}}'''",
+    ])
+    def test_fstring_fingerprint_is_one_literal(self, literal):
+        # the pre-PEP-701 token stream: the f-string is a single STRING
+        # token, so pins taken on any CPython version agree
+        import hashlib
+        h = hashlib.sha256()
+        for name, s in (("NAME", "v"), ("OP", "="), ("STRING", literal),
+                        ("NEWLINE", "\n")):
+            h.update(f"{name}\x1f{s}\x1e".encode())
+        assert normalized_fingerprint(f"v = {literal}\n") == h.hexdigest()
+
 
 def make_git_tree(tmp_path):
     """A committed throwaway git repo with one clean lintable file."""

@@ -43,6 +43,21 @@ class TestMESCServing:
         srv.run()
         assert srv.requests[1].done and srv.requests[0].done  # LO not dropped
 
+    def test_served_tokens_are_the_greedy_continuation(self):
+        """The prefill's last-position logits give the first token and
+        each decode step the next: the served tokens are the argmax of
+        one forward pass over prompt + generated[:-1]."""
+        srv = MESCServer(CFG, PARAMS, policy=Policy.mesc(), max_len=32)
+        r = _req(0, Crit.LO, 10, n=6)
+        srv.submit(r)
+        srv.run()
+        fed = np.concatenate([r.prompt, np.asarray(r.generated[:-1],
+                                                   np.int32)])
+        logits = lm.forward(CFG, PARAMS, {"tokens": jnp.asarray(fed[None])},
+                            CPU_RC)[0]
+        want = np.asarray(logits[0, len(r.prompt) - 1:]).argmax(-1)
+        assert r.generated == list(want)
+
     def test_non_preemptive_runs_to_completion(self):
         srv = MESCServer(CFG, PARAMS, policy=Policy.non_preemptive(),
                          max_len=32)
@@ -114,6 +129,48 @@ class TestMultiLaneServing:
         assert hi_lane != msrv.lane_of[0]
         ran = msrv.step()
         assert ran[hi_lane] == 1               # HI runs immediately
+
+
+class TestLaunchServe:
+    """The serving entry point: model and runtime config by platform,
+    cache length and runtime config threaded into the servers."""
+
+    def test_load_model_off_tpu_is_the_f32_smoke_config(self):
+        from repro.launch import serve
+        cfg, _, rc = serve.load_model(None)
+        assert cfg.name == "tinyllama-1.1b-smoke" and rc is CPU_RC
+
+    def test_load_model_on_tpu_is_bf16_at_full_width(self, monkeypatch):
+        from repro.launch import serve
+        from repro.models.common import DEFAULT_RC
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(lm, "init_params", lambda cfg, key, rc: None)
+        cfg, _, rc = serve.load_model(None)
+        assert (cfg.name, cfg.n_layers, cfg.d_model) == \
+            ("tinyllama-1.1b", 22, 2048)
+        assert rc is DEFAULT_RC and rc.compute_dtype == jnp.bfloat16
+
+    @pytest.mark.parametrize("lanes", [1, 2])
+    def test_run_threads_rc_and_max_len(self, monkeypatch, lanes):
+        from repro.launch import serve
+        made = []
+
+        def spy(*a, **kw):
+            made.append(kw)
+            return make(*a, **kw)
+        make = serve._make_server
+        monkeypatch.setattr(serve, "_make_server", spy)
+        reqs = serve.make_requests(CFG, np.random.default_rng(0), n_lo=2,
+                                   n_hi=1, lo_len=6, hi_len=2,
+                                   prompt_len=12)
+        out = serve.run(CFG, PARAMS, Policy.mesc(), reqs, lanes=lanes,
+                        rc=CPU_RC, max_len=40)
+        assert made == [dict(rc=CPU_RC, max_len=40, lanes=lanes,
+                             heuristic="crit_aware")]
+        assert all(len(r.prompt) == 12 and r.done
+                   and len(r.generated) == r.max_new_tokens
+                   for r in out.values())
+        assert sorted(out) == [0, 1, 2]      # the warm-up requests left
 
 
 SERVING_CASES = [
@@ -201,11 +258,12 @@ class TestVirtualServing:
         assert a == b                          # deterministic switch
         steps, t_switch, exec_s = a
         assert exec_s > 0.035                  # budget actually exceeded
-        # jitter=0: exec_s crosses 0.035 after decode step 4 (0.040);
+        # jitter=0: step 1 is the prefill (0.020), each later step one
+        # decode (0.010); exec_s crosses 0.035 after step 3 (0.040);
         # the monitor trips at the NEXT step's tick, so the loop exits
-        # after step 5 with the clock at prefill 0.020 + 5 * 0.010
-        assert steps == 5
-        assert abs(t_switch - 0.070) < 1e-9
+        # after step 4 with the clock at 0.020 + 3 * 0.010
+        assert steps == 4
+        assert abs(t_switch - 0.050) < 1e-9
         assert abs(exec_s - 0.050) < 1e-9
 
     def test_wall_clock_is_the_default(self):

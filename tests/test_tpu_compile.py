@@ -1,0 +1,133 @@
+"""Compile the device paths for a described TPU v5e, with no chip.
+
+The TPU compiler refuses here what it would refuse on the chip: a
+Pallas lowering it does not implement, a block shape off the (8, 128)
+tiling, a program that does not fit the chip's memory.  Nothing runs,
+so these tests say nothing about results or times.  The topology is
+described inside a fixture (never at import: only one process at a
+time may load the TPU library), and every test of this kind lives in
+this one file so that a single test worker loads it.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+V5E_HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # repro-lint: disable=except-breadth (any failure to describe the chip means these tests cannot run here)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def test_tinyllama_decode_step_full_width(one_chip):
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.models.common import DEFAULT_RC
+    cfg = get_config("tinyllama-1.1b")
+    assert (cfg.n_layers, cfg.d_model, cfg.d_ff) == (22, 2048, 5632)
+    params = jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.PRNGKey(0), DEFAULT_RC))
+    cache = jax.eval_shape(lambda: lm.init_cache(cfg, 1, 512, DEFAULT_RC))
+    compiled = jax.jit(
+        lambda p, t, c: lm.decode_step(cfg, p, t, c, DEFAULT_RC)).lower(
+        _shapes(params, one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip),
+        _shapes(cache, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 4e9          # f32 1.1B params
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+
+
+def _decode_attention():
+    from repro.kernels.decode_attention import decode_attention_tpu
+    return decode_attention_tpu, [((1, 32, 64), jnp.bfloat16),
+                                  ((1, 4, 512, 64), jnp.bfloat16),
+                                  ((1, 4, 512, 64), jnp.bfloat16),
+                                  ((), jnp.int32)], dict(block_s=512)
+
+
+def _flash_attention():
+    from repro.kernels.flash_attention import flash_attention_tpu
+    return flash_attention_tpu, [((1, 32, 2048, 64), jnp.bfloat16),
+                                 ((1, 4, 2048, 64), jnp.bfloat16),
+                                 ((1, 4, 2048, 64), jnp.bfloat16)], {}
+
+
+def _rglru_scan():
+    from repro.kernels.rglru_scan import rglru_scan_tpu
+    return rglru_scan_tpu, [((2, 512, 2560), jnp.float32),
+                            ((2, 512, 2560), jnp.float32),
+                            ((2, 2560), jnp.float32)], {}
+
+
+def _systolic_gemm():
+    from repro.kernels.systolic_gemm import systolic_gemm
+    return systolic_gemm, [((2048, 2048), jnp.bfloat16),
+                           ((2048, 5632), jnp.bfloat16)], {}
+
+
+@pytest.mark.parametrize("make", [_decode_attention, _flash_attention,
+                                  _rglru_scan, _systolic_gemm],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_pallas_kernel_compiles_to_a_tpu_kernel(one_chip, make):
+    kernel, args, kw = make()
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+              for s, d in args]
+    compiled = jax.jit(lambda *a: kernel(*a, **kw)).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_jit_engine_lockstep_compiles_under_x64(one_chip):
+    from repro.core import Policy
+    from repro.core import simulator_jit as sj
+    from harness import LIB, fig8_corpus
+    tasksets, seeds = fig8_corpus((0.7,), 2, 4)
+    policy = Policy.mesc()
+    duration = 2e5
+    b = sj._VecBatch(tasksets, LIB, policy, seeds=seeds, duration=duration,
+                     overrun_prob=0.3, cf=2.0)
+    run = sj._compiled_run(policy.use_banks, policy.drop_lo_in_hi,
+                           policy.preemption, True, sj._PRUNE_STALE)
+    with jax.enable_x64(True):
+        tb = sj._tables(b, seeds)
+        sc = {"t_sr": jnp.float64(policy.t_sr),
+              "overrun_prob": jnp.float64(0.3), "cf": jnp.float64(2.0),
+              "duration": jnp.float64(duration),
+              "max_steps": jnp.int64(sj._max_steps(b, duration))}
+        c0 = sj._carry0(b, seeds, 16)
+        compiled = run.lower(_shapes(tb, one_chip), _shapes(sc, one_chip),
+                             _shapes(c0, one_chip)).compile()
+    text = compiled.as_text()
+    assert "while" in text
+    assert sj.while_body_kernels(text) > 0

@@ -5,7 +5,7 @@ build* for a representative engine configuration?  The functions trace
 the jit lockstep engine exactly the way production does — same
 ``_compiled_run`` memo, same table/carry construction, same x64
 context — then walk the resulting ClosedJaxpr (recursing into
-``while``/``cond``/``scan``/``pjit``/``shard_map`` sub-jaxprs) and the
+``while``/``cond``/``scan``/``jit``/``shard_map`` sub-jaxprs) and the
 optimized HLO text to extract the measurable surface the budget
 manifests pin:
 
@@ -57,7 +57,7 @@ BANNED_PREFIXES = ("threefry", "random_")
 
 #: sub-jaxpr-owning primitives whose own eqn is compiler plumbing, not
 #: a budgetable operation (their contents are recursed into instead)
-WRAPPER_PRIMS = frozenset({"pjit", "closed_call", "custom_jvp_call",
+WRAPPER_PRIMS = frozenset({"jit", "closed_call", "custom_jvp_call",
                            "custom_vjp_call", "remat", "shard_map"})
 
 
@@ -93,7 +93,7 @@ def prepare_device_pool(n: int) -> None:
 # ----------------------------------------------------------------------
 
 def _inner(jaxpr_like):
-    """The raw eqn-bearing jaxpr: ``while``/``cond``/``pjit`` params
+    """The raw eqn-bearing jaxpr: ``while``/``cond``/``jit`` params
     hold ClosedJaxpr (unwrap ``.jaxpr``), ``shard_map`` params hold
     raw Jaxpr already — normalize both to the raw form, which has
     ``.eqns`` *and* ``.invars``."""
@@ -120,8 +120,8 @@ def walk_eqns(jaxpr_like) -> Iterable[Any]:
 
 def find_while(jaxpr_like):
     """The (first) lockstep ``while`` eqn, at any nesting depth —
-    devices=1 traces to pjit->while, sharded to
-    pjit->shard_map->while."""
+    devices=1 traces to jit->while, sharded to
+    jit->shard_map->while."""
     for eqn in walk_eqns(jaxpr_like):
         if eqn.primitive.name == "while":
             return eqn
@@ -237,9 +237,8 @@ def trace_jit_case(config: Dict[str, Any],
     """
     _ensure_paths()
     import jax
-    from jax.experimental import enable_x64
-
     import jax.numpy as jnp
+
     from repro.core import Policy
     from repro.core import simulator_jit as sj
     from repro.scenarios import get_scenario
@@ -265,7 +264,7 @@ def trace_jit_case(config: Dict[str, Any],
     run = sj._compiled_run(policy.use_banks, policy.drop_lo_in_hi,
                            policy.preemption, nominal, sj._PRUNE_STALE,
                            loop_scen, devices)
-    with enable_x64():
+    with jax.enable_x64(True):
         tb = sj._tables(b, seeds)
         sc = {"t_sr": jnp.float64(policy.t_sr),
               "overrun_prob": jnp.float64(float(spec["overrun_prob"])),

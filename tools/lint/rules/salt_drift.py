@@ -65,23 +65,61 @@ def _docstring_positions(tree: ast.Module):
     return out
 
 
+# PEP 701 (Python 3.12) tokenizes an f-string as FSTRING_START ...
+# FSTRING_END with its replacement fields as ordinary tokens; older
+# tokenizers emit the whole literal as one STRING.  Absent on < 3.12.
+_FSTRING_START = getattr(tokenize, "FSTRING_START", None)
+_FSTRING_END = getattr(tokenize, "FSTRING_END", None)
+
+
+def _source_tokens(text: str):
+    """``(type, start, string)`` of every token of ``text``, with each
+    f-string folded back into one ``STRING`` token that carries the
+    literal's source text, so the stream is the same on every CPython
+    version."""
+    lines = text.splitlines(keepends=True)
+
+    def span(start, end) -> str:
+        (r0, c0), (r1, c1) = start, end
+        if r0 == r1:
+            return lines[r0 - 1][c0:c1]
+        return (lines[r0 - 1][c0:] + "".join(lines[r0:r1 - 1])
+                + lines[r1 - 1][:c1])
+
+    depth, fstart = 0, None
+    for tok in tokenize.generate_tokens(io.StringIO(text).readline):
+        if _FSTRING_START is not None and tok.type == _FSTRING_START:
+            if depth == 0:
+                fstart = tok.start
+            depth += 1
+            continue
+        if depth:
+            if tok.type == _FSTRING_END:
+                depth -= 1
+                if depth == 0:
+                    yield tokenize.STRING, fstart, span(fstart, tok.end)
+            continue
+        yield tok.type, tok.start, tok.string
+
+
 def normalized_fingerprint(text: str) -> str:
     """sha256 over the comment-/docstring-/formatting-insensitive
     token stream of one Python source text.
 
     Token *names* (not version-dependent numeric codes) key the
-    stream, so the hash is stable across CPython minor versions.
+    stream, and f-strings hash as one literal (:func:`_source_tokens`),
+    so the hash is stable across CPython minor versions.
     """
     doc_pos = _docstring_positions(ast.parse(text))
     h = hashlib.sha256()
-    for tok in tokenize.generate_tokens(io.StringIO(text).readline):
-        if tok.type in _SKIP_TOKENS:
+    for typ, start, string in _source_tokens(text):
+        if typ in _SKIP_TOKENS:
             continue
-        if tok.type == tokenize.STRING and tok.start in doc_pos:
+        if typ == tokenize.STRING and start in doc_pos:
             continue
-        h.update(tokenize.tok_name[tok.type].encode())
+        h.update(tokenize.tok_name[typ].encode())
         h.update(b"\x1f")
-        h.update(tok.string.encode())
+        h.update(string.encode())
         h.update(b"\x1e")
     return h.hexdigest()
 
