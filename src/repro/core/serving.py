@@ -20,6 +20,17 @@ zero-arg callable returning seconds.  The default is the wall clock
 switches and all SLO metrics deterministic (see docs/serving.md for
 the clock-injection contract).
 
+Each ``step()`` that runs an instruction appends a :class:`StepRecord`
+to ``Request.steps`` of the request it ran: five clock stamps that
+delimit the spans ``mesc.schedule`` (scheduler pass, with the
+``mesc.save``/``mesc.restore`` context switches inside it),
+``mesc.launch`` (input copy and program launch), ``mesc.wait`` (the
+instruction in flight as the host sees it, up to its token on the
+host) and ``mesc.finish`` (termination check, bank flush), all inside
+``mesc.step``, and the number of blocking device reads.  The same spans
+go into the profiler trace as ``jax.profiler.TraceAnnotation``s, which
+cost well under a microsecond each while no trace is recorded.
+
 Scheduling follows scheduler.Policy + mode rules: HI requests preempt LO
 requests at instruction (= decode-step) boundaries; LO requests are never
 dropped (imprecise-MCS stance), they run when no HI request is active.
@@ -35,7 +46,8 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +58,25 @@ from repro.core.scheduler import Mode, Policy
 from repro.core.task import Crit
 from repro.models import lm
 from repro.models.common import RuntimeConfig, CPU_RC
+
+_span = jax.profiler.TraceAnnotation
+
+
+class StepRecord(NamedTuple):
+    """One instruction as ``MESCServer.step`` ran it; every stamp is
+    read from the server's clock.  Spans: ``mesc.step`` [start, end),
+    ``mesc.schedule`` [start, launch) of which ``cs_s`` went to context
+    saves and restores, ``mesc.launch`` [launch, returned),
+    ``mesc.wait`` [returned, token), ``mesc.finish`` [token, end)."""
+    kind: str                       # "prefill" or "decode"
+    tokens: int                     # prompt tokens fed (0 for a decode)
+    start: float
+    launch: float
+    returned: float
+    token: float
+    end: float
+    syncs: int                      # blocking device-to-host reads
+    cs_s: float
 
 
 @dataclasses.dataclass
@@ -70,6 +101,30 @@ class Request:
     finished_at: Optional[float] = None
     preemptions: int = 0
     saves: int = 0
+    # spans and counters (module docstring): one record per instruction
+    steps: List[StepRecord] = dataclasses.field(default_factory=list)
+    save_s: float = 0.0             # context saves: time, bytes moved
+    save_bytes: int = 0
+    restore_s: float = 0.0
+    restore_bytes: int = 0
+    # HI only: time after submitted_at in which a LO instruction held
+    # the lane, before this request's first dispatch
+    blocked_s: float = 0.0
+
+
+def _nbytes(tree) -> int:
+    return sum(np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(tree))
+
+
+def _programs(cfg: ArchConfig, rc: RuntimeConfig, max_len: int):
+    """The jitted ``(decode, prefill)`` pair, named so that XLA calls
+    the modules ``jit_mesc_decode`` and ``jit_mesc_prefill``."""
+    def mesc_decode(p, t, c):
+        return lm.decode_step(cfg, p, t, c, rc)
+
+    def mesc_prefill(p, b):
+        return lm.prefill(cfg, p, b, rc, max_len=max_len)
+    return jax.jit(mesc_decode), jax.jit(mesc_prefill)
 
 
 class KVSlotArena:
@@ -140,13 +195,13 @@ class MESCServer:
         # timestamp below reads self.clock(), never time.monotonic()
         self.clock = clock
         self._cs_save_s, self._cs_restore_s = cs_costs or (0.0, 0.0)
-        if jit_fns is not None:            # shared across lanes
-            self._decode, self._prefill = jit_fns
-        else:
-            self._decode = jax.jit(
-                lambda p, t, c: lm.decode_step(cfg, p, t, c, rc))
-            self._prefill = jax.jit(
-                lambda p, b: lm.prefill(cfg, p, b, rc, max_len=max_len))
+        self._decode, self._prefill = jit_fns or _programs(cfg, rc, max_len)
+        # what the current step() has counted so far
+        self._syncs, self._cs_s = 0, 0.0
+        # blocked_s bookkeeping: the last step, if it ran LO, as
+        # (start, end); the HI requests submitted and not yet started
+        self._last_lo: Optional[Tuple[float, float]] = None
+        self._hi_waiting: Dict[int, Request] = {}
 
     def _charge(self, dt: float) -> None:
         """Charge a modeled context-switch cost to an advanceable
@@ -162,10 +217,16 @@ class MESCServer:
                 if r.resident and not r.done]
 
     def _evict(self, victim: Request):
-        victim.cache = jax.device_get(victim.cache)       # step_wise_mvout
+        t = self.clock()
+        with _span("mesc.save"):
+            victim.cache = jax.device_get(victim.cache)   # step_wise_mvout
+            self._charge(self._cs_save_s)
+        dt = self.clock() - t
+        victim.save_s += dt
+        victim.save_bytes += _nbytes(victim.cache)
+        self._cs_s += dt
         victim.resident = False
         victim.saves += 1
-        self._charge(self._cs_save_s)
         self.arena.release(self.lane, victim.rid)
 
     def _make_room(self, incoming: Request):
@@ -180,8 +241,14 @@ class MESCServer:
     def _restore(self, r: Request):
         self.arena.acquire(self.lane, r.rid)
         if r.cache is not None and not r.resident:
-            r.cache = jax.device_put(r.cache)             # step_wise_mvin
-            self._charge(self._cs_restore_s)
+            t = self.clock()
+            r.restore_bytes += _nbytes(r.cache)
+            with _span("mesc.restore"):
+                r.cache = jax.device_put(r.cache)         # step_wise_mvin
+                self._charge(self._cs_restore_s)
+            dt = self.clock() - t
+            r.restore_s += dt
+            self._cs_s += dt
         r.resident = True
 
     # -- scheduling ---------------------------------------------------------
@@ -189,6 +256,13 @@ class MESCServer:
         if r.submitted_at is None:         # front door may pre-stamp the
             r.submitted_at = self.clock()  # true arrival time
         self.requests[r.rid] = r
+        if r.crit == Crit.HI and r.started_at is None:
+            # the LO step it arrived during ran before the server knew
+            # of it: count that step's rest now
+            if self._last_lo is not None:
+                start, end = self._last_lo
+                r.blocked_s += max(0.0, end - max(start, r.submitted_at))
+            self._hi_waiting[r.rid] = r
 
     def _eligible(self) -> List[Request]:
         live = [r for r in self.requests.values() if not r.done]
@@ -243,11 +317,9 @@ class MESCServer:
                                            #  here: saves are synchronous)
 
     # -- the serve loop -----------------------------------------------------
-    def step(self) -> Optional[int]:
-        """One scheduler invocation + one instruction, which emits one
-        token: the prompt's prefill for a request's first token, one
-        decode step for every later one.  Returns the rid that ran, or
-        None if idle."""
+    def _schedule(self) -> Optional[Request]:
+        """The scheduler pass: the request to run next, made resident,
+        or None if idle."""
         self._mode_tick()
         r = self._pick()
         # non-preemptive baseline: a started request owns the accelerator
@@ -265,29 +337,67 @@ class MESCServer:
         if not r.resident:
             self._make_room(r)
             self._restore(r)
-        if r.started_at is None:
-            r.started_at = self.clock()
-        t0 = self.clock()
-        if r.cache is None:                # last-position prefill logits
-            logits, r.cache = self._prefill(
-                self.params, {"tokens": jnp.asarray(r.prompt[None])})
+        return r
+
+    def _read(self, x) -> int:
+        """``int(x)``; a read from the device is counted in the step."""
+        self._syncs += isinstance(x, jax.Array)
+        return int(x)
+
+    def step(self) -> Optional[int]:
+        """One scheduler invocation + one instruction, which emits one
+        token: the prompt's prefill for a request's first token, one
+        decode step for every later one.  Returns the rid that ran, or
+        None if idle."""
+        start = self.clock()
+        self._syncs, self._cs_s = 0, 0.0
+        with _span("mesc.step"):
+            with _span("mesc.schedule"):
+                r = self._schedule()
+            if r is None:
+                self._last_lo = None
+                return None
+            launch = self.clock()
+            if r.started_at is None:
+                r.started_at = launch
+                self._hi_waiting.pop(r.rid, None)
+            prefill = r.cache is None
+            with _span("mesc.launch"):
+                if prefill:                # last-position prefill logits
+                    logits, r.cache = self._prefill(
+                        self.params, {"tokens": jnp.asarray(r.prompt[None])})
+                else:
+                    logits, r.cache = self._decode(
+                        self.params, jnp.asarray([r.generated[-1]], jnp.int32),
+                        r.cache)
+            returned = self.clock()
+            with _span("mesc.wait"):
+                tok = self._read(jnp.argmax(logits[0]))
+            token = self.clock()
+            r.generated.append(tok)
+            r.exec_s += token - launch
+            if r.first_token_at is None:
+                r.first_token_at = token
+            with _span("mesc.finish"):
+                if len(r.generated) >= r.max_new_tokens \
+                        or self._read(r.cache["pos"]) >= self.max_len - 1:
+                    r.done = True
+                    r.finished_at = self.clock()
+                    r.resident = False
+                    r.cache = None         # flush banks
+                    self.arena.release(self.lane, r.rid)
+                    self.current = None
+            end = self.clock()
+        r.steps.append(StepRecord(
+            "prefill" if prefill else "decode",
+            len(r.prompt) if prefill else 0,
+            start, launch, returned, token, end, self._syncs, self._cs_s))
+        if r.crit == Crit.LO:
+            self._last_lo = (start, end)
+            for h in self._hi_waiting.values():
+                h.blocked_s += max(0.0, end - max(start, h.submitted_at))
         else:
-            logits, r.cache = self._decode(
-                self.params, jnp.asarray([r.generated[-1]], jnp.int32),
-                r.cache)
-        tok = int(jnp.argmax(logits[0]))
-        r.generated.append(tok)
-        r.exec_s += self.clock() - t0
-        if r.first_token_at is None:
-            r.first_token_at = self.clock()
-        if len(r.generated) >= r.max_new_tokens \
-                or int(r.cache["pos"]) >= self.max_len - 1:
-            r.done = True
-            r.finished_at = self.clock()
-            r.resident = False
-            r.cache = None                 # flush banks
-            self.arena.release(self.lane, r.rid)
-            self.current = None
+            self._last_lo = None
         return r.rid
 
     def run(self, max_steps: int = 10_000) -> Dict[int, Request]:
@@ -335,11 +445,7 @@ class MultiLaneServer:
         # virtual-clock harness injects per-lane (decode, prefill)
         # pairs instead (each bound to its own lane clock)
         if jit_fns is None:
-            decode = jax.jit(
-                lambda p, t, c: lm.decode_step(cfg, p, t, c, rc))
-            prefill = jax.jit(
-                lambda p, b: lm.prefill(cfg, p, b, rc, max_len=max_len))
-            per_lane_fns = [(decode, prefill)] * n_lanes
+            per_lane_fns = [_programs(cfg, rc, max_len)] * n_lanes
         elif callable(jit_fns[0]):                     # one shared pair
             per_lane_fns = [tuple(jit_fns)] * n_lanes
         else:                                          # per-lane pairs

@@ -131,7 +131,11 @@ def summarize(name, reqs):
         out[crit.value] = (np.mean(ttft), np.mean(lat))
         print(f"  {name:12s} {crit.value}: ttft={np.mean(ttft)*1e3:7.1f} ms "
               f"latency={np.mean(lat)*1e3:7.1f} ms  n={len(rs)} "
-              f"saves={sum(r.saves for r in rs)}")
+              f"saves={sum(r.saves for r in rs)} "
+              f"save_ms={sum(r.save_s for r in rs)*1e3:.1f} "
+              f"save_bytes={sum(r.save_bytes for r in rs)} "
+              f"restore_ms={sum(r.restore_s for r in rs)*1e3:.1f} "
+              f"restore_bytes={sum(r.restore_bytes for r in rs)}")
     return out
 
 

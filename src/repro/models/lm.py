@@ -13,6 +13,10 @@ All entry points are pure functions of (cfg, params, ...):
 
 Layer params are stacked along a leading scan dim; caches mirror that
 stacking so decode scans layers with (params, cache) as xs/ys.
+
+The dense block parts run under a ``jax.named_scope`` (``embed``,
+``attn``, ``mlp``, ``logits``): metadata only, so that a device trace's
+operations name the part they belong to.
 """
 from __future__ import annotations
 
@@ -252,6 +256,7 @@ def init_params(cfg: ArchConfig, key, rc: RuntimeConfig = DEFAULT_RC) -> Params:
 # Embedding / heads (modality frontends are stubs per the assignment)
 # ===========================================================================
 
+@jax.named_scope("embed")
 def embed_inputs(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
                  rc: RuntimeConfig):
     """Returns h (B, S, D)."""
@@ -272,6 +277,7 @@ def embed_inputs(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
     return h
 
 
+@jax.named_scope("logits")
 def lm_logits(cfg: ArchConfig, params: Params, h, rc: RuntimeConfig):
     h = apply_norm(cfg.norm, h, params["out_norm"])
     w = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
@@ -285,6 +291,7 @@ def lm_logits(cfg: ArchConfig, params: Params, h, rc: RuntimeConfig):
 # Block bodies (single layer, full-sequence mode)
 # ===========================================================================
 
+@jax.named_scope("attn")
 def _attn_full(cfg, rc, h, p, positions, *, window=None, make_cache=False):
     x = apply_norm(cfg.norm, h, p["ln"])
     q, k, v = attn_lib.gqa_project_qkv(x, p, cfg, positions)
@@ -360,6 +367,7 @@ def _mla_full(cfg, rc, h, p, positions, *, make_cache=False):
     return h, ((c, kr) if make_cache else None)
 
 
+@jax.named_scope("mlp")
 def _mlp_full(cfg, rc, h, p, *, act="swiglu"):
     x = apply_norm(cfg.norm, h, p["ln"])
     y = ffn_lib.swiglu(x, p) if act == "swiglu" else ffn_lib.geglu(x, p)
@@ -724,6 +732,7 @@ def prefill(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
 
 # --- per-family decode bodies ----------------------------------------------
 
+@jax.named_scope("attn")
 def _attn_decode(cfg, rc, h, p, ck, cv, pos, positions, window=None):
     x = apply_norm(cfg.norm, h, p["ln"])
     q, k, v = attn_lib.gqa_project_qkv(x, p, cfg, positions)
