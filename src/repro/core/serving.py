@@ -116,6 +116,14 @@ def _nbytes(tree) -> int:
     return sum(np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(tree))
 
 
+def _cast_bytes(params, step_params) -> int:
+    """Bytes of the leaves of ``step_params`` that are not ``params``'
+    own: the copy ``lm.step_params`` made."""
+    return sum(b.nbytes for a, b in zip(jax.tree_util.tree_leaves(params),
+                                        jax.tree_util.tree_leaves(step_params))
+               if a is not b)
+
+
 def _programs(cfg: ArchConfig, rc: RuntimeConfig, max_len: int):
     """The jitted ``(decode, prefill)`` pair, named so that XLA calls
     the modules ``jit_mesc_decode`` and ``jit_mesc_prefill``."""
@@ -183,6 +191,11 @@ class MESCServer:
                  cs_costs: Optional[Tuple[float, float]] = None):
         self.cfg = cfg
         self.params = params
+        # the step programs read the weights in the compute dtype, cast
+        # once here (lm.step_params) and not on every call; cast_bytes
+        # is the size of that copy, 0 when nothing needed a cast
+        self._step_params = lm.step_params(cfg, params, rc)
+        self.cast_bytes = _cast_bytes(params, self._step_params)
         self.rc = rc
         self.policy = policy or Policy.mesc()
         self.max_len = max_len
@@ -365,11 +378,12 @@ class MESCServer:
             with _span("mesc.launch"):
                 if prefill:                # last-position prefill logits
                     logits, r.cache = self._prefill(
-                        self.params, {"tokens": jnp.asarray(r.prompt[None])})
+                        self._step_params,
+                        {"tokens": jnp.asarray(r.prompt[None])})
                 else:
                     logits, r.cache = self._decode(
-                        self.params, jnp.asarray([r.generated[-1]], jnp.int32),
-                        r.cache)
+                        self._step_params,
+                        jnp.asarray([r.generated[-1]], jnp.int32), r.cache)
             returned = self.clock()
             with _span("mesc.wait"):
                 tok = self._read(jnp.argmax(logits[0]))
@@ -426,7 +440,9 @@ class MultiLaneServer:
     so lanes progress in lockstep rounds; a HI request only ever
     contends with its own lane's requests — the partitioned-blocking
     win the multi-accelerator analysis (``wcrt.analyze_partitioned``)
-    quantifies.
+    quantifies.  The weights are cast to the compute dtype once, here
+    (``lm.step_params``), and every lane is handed that one copy as its
+    ``params``; ``cast_bytes`` is its size.
     """
 
     def __init__(self, cfg: ArchConfig, params, *, n_lanes: int = 2,
@@ -441,6 +457,8 @@ class MultiLaneServer:
         total_slots = total_slots if total_slots is not None else 2 * n_lanes
         self.arena = KVSlotArena(total_slots, n_lanes)
         self.heuristic = heuristic
+        step_params = lm.step_params(cfg, params, rc)
+        self.cast_bytes = _cast_bytes(params, step_params)
         # dispatch functions: one shared jitted pair by default; the
         # virtual-clock harness injects per-lane (decode, prefill)
         # pairs instead (each bound to its own lane clock)
@@ -464,7 +482,8 @@ class MultiLaneServer:
                                  f"{n_lanes} lanes")
             per_lane_clocks = list(clocks)
         self.lanes: List[MESCServer] = [
-            MESCServer(cfg, params, policy=policy, rc=rc, max_len=max_len,
+            MESCServer(cfg, step_params, policy=policy, rc=rc,
+                       max_len=max_len,
                        arena=self.arena, lane=i, jit_fns=per_lane_fns[i],
                        clock=per_lane_clocks[i], cs_costs=cs_costs)
             for i in range(n_lanes)]

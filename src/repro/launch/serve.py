@@ -83,10 +83,14 @@ def make_requests(cfg, rng, n_lo: int = 4, n_hi: int = 2,
 def _make_server(cfg, params, policy, *, rc, max_len: int, lanes: int,
                  heuristic: str):
     if lanes > 1:
-        return MultiLaneServer(cfg, params, policy=policy, rc=rc,
-                               max_len=max_len, n_lanes=lanes,
-                               heuristic=heuristic)
-    return MESCServer(cfg, params, policy=policy, rc=rc, max_len=max_len)
+        srv = MultiLaneServer(cfg, params, policy=policy, rc=rc,
+                              max_len=max_len, n_lanes=lanes,
+                              heuristic=heuristic)
+    else:
+        srv = MESCServer(cfg, params, policy=policy, rc=rc, max_len=max_len)
+    print(f"  weights cast to the compute dtype at build: "
+          f"{srv.cast_bytes} bytes")
+    return srv
 
 
 def _warm(srv, prompt_lens) -> None:

@@ -8,8 +8,8 @@ Families:
   xlstm               : scan over groups of (mLSTM ... sLSTM)
 
 All entry points are pure functions of (cfg, params, ...):
-  init_params, forward (train/prefill), loss_fn, init_cache, prefill,
-  decode_step.
+  init_params, step_params (the weights the serving steps read),
+  forward (train/prefill), loss_fn, init_cache, prefill, decode_step.
 
 Layer params are stacked along a leading scan dim; caches mirror that
 stacking so decode scans layers with (params, cache) as xs/ys.
@@ -250,6 +250,59 @@ def init_params(cfg: ArchConfig, key, rc: RuntimeConfig = DEFAULT_RC) -> Params:
     else:
         raise ValueError(fam)
     return params
+
+
+# The leaves of the dense, vlm and audio decoder that prefill and
+# decode_step read only through a cast to rc.compute_dtype, before any
+# arithmetic.  Not among them: norm scales (read in f32) and the audio
+# embedding (summed over codebooks before its cast, embed_inputs).
+_READ_AS_COMPUTE = frozenset(
+    [("lm_head",)]
+    + [("blocks", "attn", w) for w in ("wq", "wk", "wv", "wo",
+                                       "bq", "bk", "bv")]
+    + [("blocks", "mlp", w) for w in ("w1", "w2", "w3")])
+
+
+def _read_as_compute(cfg: ArchConfig, keys: Tuple) -> bool:
+    if cfg.family not in ("dense", "vlm", "audio"):
+        return False
+    if keys == ("embed",):
+        return cfg.family != "audio"
+    return keys in _READ_AS_COMPUTE
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast(leaves, dtype):
+    return [x.astype(dtype) for x in leaves]
+
+
+def step_params(cfg: ArchConfig, params: Params,
+                rc: RuntimeConfig = DEFAULT_RC) -> Params:
+    """The weights that ``prefill`` and ``decode_step`` should be fed.
+
+    Each leaf they read only through a cast to ``rc.compute_dtype`` is
+    cast to it here, once, in one jitted call, so the step programs no
+    longer convert it on every call; each einsum reads the values its
+    own cast would make.  (XLA may compile the f32-fed program with
+    more precision than its casts ask for: on a TPU it reads f32
+    embedding bits, so the logits there can differ in the last bf16
+    place; on the CPU they are bit-equal.)  Only narrowing casts are
+    made: a wider copy would hold more bytes than the weights
+    themselves.  Every other leaf is the input's own object, and
+    ``params`` itself is returned when nothing is cast (weights already
+    in the compute dtype, or f32 compute)."""
+    dt = jnp.dtype(rc.compute_dtype)
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    picked = [i for i, (path, x) in enumerate(flat)
+              if jnp.dtype(x.dtype).itemsize > dt.itemsize
+              and _read_as_compute(cfg, tuple(getattr(k, "key", None)
+                                              for k in path))]
+    if not picked:
+        return params
+    leaves = [x for _, x in flat]
+    for i, y in zip(picked, _cast([leaves[i] for i in picked], dt)):
+        leaves[i] = y
+    return jax.tree_util.tree_unflatten(tree, leaves)
 
 
 # ===========================================================================

@@ -173,6 +173,85 @@ class TestLaunchServe:
         assert sorted(out) == [0, 1, 2]      # the warm-up requests left
 
 
+def _cast_leaf_shapes(params):
+    """Shapes of the leaves ``lm.step_params`` casts for a dense model."""
+    sel = [params["embed"], params.get("lm_head")] + [
+        params["blocks"][part][w] for part, ws in
+        (("attn", ("wq", "wk", "wv", "wo")), ("mlp", ("w1", "w2", "w3")))
+        for w in ws]
+    return [x.shape for x in sel if x is not None]
+
+
+class TestStepWeights:
+    """The step programs read a compute-dtype copy of the weights made
+    once at server build (``lm.step_params``), not the caller's f32
+    tree: the same tokens, one copy for every lane."""
+
+    def test_bf16_server_serves_the_tokens_of_the_f32_fed_programs(self):
+        from repro.core.serving import _programs
+        from repro.models.common import DEFAULT_RC
+        srv = MESCServer(CFG, PARAMS, rc=DEFAULT_RC, max_len=32)
+        assert srv.params is PARAMS
+        reqs = [_req(0, Crit.LO, 10, n=6), _req(1, Crit.HI, 0, n=4),
+                _req(2, Crit.LO, 11, n=5)]
+        for r in reqs:
+            srv.submit(r)
+        srv.run()
+        decode, prefill = _programs(CFG, DEFAULT_RC, 32)
+        for r in reqs:
+            logits, cache = prefill(PARAMS,
+                                    {"tokens": jnp.asarray(r.prompt[None])})
+            want = [int(jnp.argmax(logits[0]))]
+            while len(want) < r.max_new_tokens:
+                logits, cache = decode(PARAMS, jnp.asarray([want[-1]]),
+                                       cache)
+                want.append(int(jnp.argmax(logits[0])))
+            assert r.generated == want
+
+    def test_cast_bytes_counts_the_copy(self):
+        from repro.models.common import DEFAULT_RC, RuntimeConfig
+        srv = MESCServer(CFG, PARAMS, rc=DEFAULT_RC, max_len=32)
+        assert srv.cast_bytes == sum(
+            2 * int(np.prod(s)) for s in _cast_leaf_shapes(PARAMS))
+        bf16 = lm.init_params(CFG, jax.random.PRNGKey(0),
+                              RuntimeConfig(param_dtype=jnp.bfloat16))
+        assert MESCServer(CFG, bf16, rc=DEFAULT_RC).cast_bytes == 0
+        assert MESCServer(CFG, PARAMS, rc=CPU_RC).cast_bytes == 0
+
+    def test_lanes_share_one_cast_copy(self):
+        from repro.core.serving import MultiLaneServer
+        from repro.models.common import DEFAULT_RC
+        msrv = MultiLaneServer(CFG, PARAMS, n_lanes=2, rc=DEFAULT_RC,
+                               max_len=32)
+        one = MESCServer(CFG, PARAMS, rc=DEFAULT_RC, max_len=32)
+        assert msrv.cast_bytes == one.cast_bytes > 0
+        a, b = (jax.tree_util.tree_leaves(ln._step_params)
+                for ln in msrv.lanes)
+        assert all(x is y for x, y in zip(a, b))
+        w1 = msrv.lanes[0]._step_params["blocks"]["mlp"]["w1"]
+        assert w1.dtype == jnp.bfloat16
+
+    def test_decode_program_takes_no_f32_weight(self):
+        """The lowered ``jit_mesc_decode`` has no f32 parameter of a cast
+        weight's shape; fed the caller's tree, as before, it has."""
+        import re
+        from repro.models.common import DEFAULT_RC
+        srv = MESCServer(CFG, PARAMS, rc=DEFAULT_RC, max_len=32)
+        cache = lm.init_cache(CFG, 1, 32, DEFAULT_RC)
+        tok = jnp.zeros((1,), jnp.int32)
+        shapes = {"x".join(map(str, s)) for s in _cast_leaf_shapes(PARAMS)}
+
+        def f32_weight_args(p):
+            text = srv._decode.lower(p, tok, cache).as_text()
+            sig = next(ln for ln in text.splitlines()
+                       if "func.func public @main(" in ln)
+            return [s for s in re.findall(r"%arg\d+: tensor<([0-9x]+)xf32>",
+                                          sig) if s in shapes]
+
+        assert f32_weight_args(srv._step_params) == []
+        assert f32_weight_args(PARAMS)
+
+
 SERVING_CASES = [
     ServingCase("mesc-poisson-sat", policy="mesc", arrivals="poisson"),
     ServingCase("np-poisson-sat", policy="np", arrivals="poisson"),

@@ -69,6 +69,44 @@ def test_tinyllama_decode_step_full_width(one_chip):
         < V5E_HBM_BYTES
 
 
+def test_tinyllama_decode_on_step_params_converts_no_weight(one_chip):
+    """Fed ``lm.step_params``' bf16 copy, the compiled decode step holds
+    no conversion of the stacked layer weights and no per-step copy of
+    them; fed the f32 tree, it converts them all on each call.  (The
+    head's f32 multiply inside its matrix-vector fusion is XLA's own
+    precision handling of a bf16 product, on either tree.)"""
+    import re
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.models.common import DEFAULT_RC
+    cfg = get_config("tinyllama-1.1b")
+    params = jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.PRNGKey(0), DEFAULT_RC))
+    cast = jax.eval_shape(lambda p: lm.step_params(cfg, p, DEFAULT_RC),
+                          params)
+    cache = jax.eval_shape(lambda: lm.init_cache(cfg, 1, 512, DEFAULT_RC))
+    weights = {",".join(map(str, a.shape)) for a in
+               jax.tree_util.tree_leaves(cast["blocks"])
+               if a.dtype == jnp.bfloat16}
+
+    def compile_for(tree):
+        return jax.jit(
+            lambda p, t, c: lm.decode_step(cfg, p, t, c, DEFAULT_RC)).lower(
+            _shapes(tree, one_chip),
+            jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip),
+            _shapes(cache, one_chip)).compile()
+
+    def weight_converts(compiled):
+        return [s for s in re.findall(r"= bf16\[([0-9,]+)\]\S* convert\(",
+                                      compiled.as_text()) if s in weights]
+
+    before, after = compile_for(params), compile_for(cast)
+    assert len(weight_converts(before)) >= 7         # wq wk wv wo w1 w2 w3
+    assert before.memory_analysis().temp_size_in_bytes > 1e9
+    assert weight_converts(after) == []
+    assert after.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 def _decode_attention():
     from repro.kernels.decode_attention import decode_attention_tpu
     return decode_attention_tpu, [((1, 32, 64), jnp.bfloat16),
