@@ -1,16 +1,12 @@
-"""Plain float32 reference of a dense decoder, for the correctness check.
+"""Plain float32 reference of a model, for the correctness check.
 
 Straightforward ``jax.numpy`` at ``Precision.HIGHEST``, read from the
-configuration file alone and importing nothing of the program: token
-embedding; per layer a pre-norm, multi-head (grouped-query) attention
-with rotary positions (rotate-half, over ``partial_rotary_factor`` of
-each head), a residual add, a pre-norm SwiGLU MLP and a residual add;
-a final norm and the output head (the embedding, where tied).
-
-It runs one layer at a time from the stacked weights (each layer is
-upcast inside its own call), attends in blocks of query rows, and
-computes the head only at the rows asked for, so that it fits beside
-the served weights of a 3.8B model on one chip.
+configuration file alone and importing nothing of the program.  The
+layers themselves are the configuration's family's
+(``bench/families/<family>.py``); this module holds what every family
+builds them from: a matrix product at the reference's or the control's
+precision (:func:`_mm`), the norms, rotary positions, causal attention
+in blocks of query rows, and the padded length a sequence runs at.
 
 ``quant`` makes the control: the same arithmetic with every matrix
 product taken in the precision below the one the configuration serves
@@ -20,13 +16,13 @@ fail the check that the program passes.
 """
 from __future__ import annotations
 
-import functools
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import dims
+from bench import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FP8_MAX = 448.0                      # largest finite float8_e4m3fn
@@ -96,44 +92,6 @@ def _attention(q, k, v):
     return jnp.concatenate(outs, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("conf_items", "quant"))
-def _layer(h, blocks, i, *, conf_items, quant):
-    conf = dict(conf_items)
-    n = dims(conf)
-    p = jax.tree.map(lambda a: a[i], blocks)
-    S = h.shape[0]
-    pos = jnp.arange(S)
-    x = _norm(conf, h, p["attn"]["ln"])
-    q = _mm(x, p["attn"]["wq"], quant).reshape(S, n["H"], n["dh"])
-    k = _mm(x, p["attn"]["wk"], quant).reshape(S, n["Hkv"], n["dh"])
-    v = _mm(x, p["attn"]["wv"], quant).reshape(S, n["Hkv"], n["dh"])
-    o = _attention(_rope(conf, q, pos), _rope(conf, k, pos), v)
-    h = h + _mm(o.reshape(S, -1), p["attn"]["wo"], quant)
-    x = _norm(conf, h, p["mlp"]["ln"])
-    y = jax.nn.silu(_mm(x, p["mlp"]["w1"], quant)) \
-        * _mm(x, p["mlp"]["w3"], quant)
-    return h + _mm(y, p["mlp"]["w2"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("conf_items", "quant"))
-def _head(h_rows, params, *, conf_items, quant):
-    conf = dict(conf_items)
-    x = _norm(conf, h_rows, params["out_norm"])
-    w = params["embed"].T if conf["tie_word_embeddings"] \
-        else params["lm_head"]
-    return _mm(x, w, quant)
-
-
-def _items(conf: dict):
-    """The hashable part of a configuration the reference reads."""
-    keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
-            "head_dim", "intermediate_size", "vocab_size",
-            "num_hidden_layers", "tie_word_embeddings", "layer_norm_type",
-            "layer_norm_eps", "rms_norm_eps", "rope_theta",
-            "partial_rotary_factor")
-    return tuple((k, conf[k]) for k in keys if k in conf)
-
-
 def padded_len(n: int, max_len: int) -> int:
     """Length the reference runs a sequence at: the next power of two
     from 512 up, at most ``max_len`` (a few shapes, each compiled once;
@@ -145,17 +103,8 @@ def padded_len(n: int, max_len: int) -> int:
 
 
 def logits_at(conf: dict, params, tokens: np.ndarray, rows: np.ndarray,
-              quant: str = "none") -> np.ndarray:
+              quant: str = "none", root: Path = spec.ROOT) -> np.ndarray:
     """float32 logits (len(rows), V) of the sequence ``tokens`` at the
     positions ``rows`` (each sees the tokens up to and including it)."""
-    items = _items(conf)
-    T = padded_len(len(tokens), conf["serving"]["max_len"])
-    toks = np.zeros(T, np.int32)
-    toks[:len(tokens)] = tokens
-    h = jnp.take(params["embed"], jnp.asarray(toks), axis=0) \
-        .astype(jnp.float32)
-    for i in range(conf["num_hidden_layers"]):
-        h = _layer(h, params["blocks"], jnp.int32(i), conf_items=items,
-                   quant=quant)
-    out = _head(h[jnp.asarray(rows)], params, conf_items=items, quant=quant)
-    return np.asarray(out, np.float32)
+    return spec.family(conf, root).logits_at(conf, params, tokens, rows,
+                                             quant)

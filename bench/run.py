@@ -5,14 +5,16 @@
 
 From the root of a checkout: the cell's configuration, traffic mix and
 metrics are found by name through ``BENCHMARK.json`` (``bench/spec.py``).
-Set-up makes the weights from the seed, builds ``MESCServer`` with
-``Policy.mesc()`` and the configuration's ``max_len`` and slots, and
-serves one request of every prompt length the mix sends, so that every
-program is compiled (or read from the persistent cache) before the
-window opens; ``setup_s`` counts all of it.  The window then serves the
-mix for ``--seconds`` (``bench/drive.py``).  Once it has closed, the
-peak device memory is read, the server's state is freed and the output
-is checked against the plain reference (``bench/check.py``).
+Set-up asks the configuration's family (``bench/families/``) for the
+program's architecture, the shape check and the weights, made from the
+seed; it builds ``MESCServer`` with ``Policy.mesc()`` and the
+configuration's ``max_len`` and slots, and serves one request of every
+prompt length the mix sends, so that every program is compiled (or
+read from the persistent cache) before the window opens; ``setup_s``
+counts all of it.  The window then serves the mix for ``--seconds``
+(``bench/drive.py``).  Once it has closed, the peak device memory is
+read, the server's state is freed and the output is checked against
+the plain reference (``bench/check.py``).
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
 records a profiler trace of the window and reports the per-layer
@@ -29,7 +31,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
@@ -59,18 +60,9 @@ def device_info(chips: int) -> dict:
     return dev
 
 
-def check_arch(conf: dict, cfg) -> None:
+def check_arch(conf: dict, cfg, root: Path = ROOT) -> None:
     """Raise unless the program's configuration is the file's shape."""
-    n = weights.dims(conf)
-    want = {"family": "dense", "n_layers": n["L"], "d_model": n["d"],
-            "n_heads": n["H"], "n_kv_heads": n["Hkv"], "dh": n["dh"],
-            "d_ff": n["f"], "vocab": n["V"], "tie_embeddings": n["tied"],
-            "rope_theta": conf["rope_theta"], "qkv_bias": False,
-            "norm": "rmsnorm" if n["rms"] else "layernorm_nonparam"}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise ValueError(f"{conf['name']}: the program's {cfg.name} is "
-                         f"{got}, the configuration file says {want}")
+    spec.family(conf, root).check_arch(conf, cfg)
 
 
 def configure_cache() -> str:
@@ -83,26 +75,22 @@ def configure_cache() -> str:
     return path
 
 
-def setup(cell: spec.Cell, seed: int):
+def setup(cell: spec.Cell, seed: int, root: Path = ROOT):
     """Weights from the seed, and a warmed server: ``(params, server)``."""
-    from repro.configs import get_config
     from repro.models import lm
     from repro.models.common import RuntimeConfig
     conf = cell.config
-    # a head tied to the embedding or not is the program's option: set
-    # as the configuration file states it
-    cfg = dataclasses.replace(get_config(conf["arch"]),
-                              tie_embeddings=weights.dims(conf)["tied"])
-    check_arch(conf, cfg)
+    cfg = spec.family(conf, root).program_config(conf)
+    check_arch(conf, cfg, root)
     s = conf["serving"]
     rc = RuntimeConfig(compute_dtype=weights.DTYPES[s["compute_dtype"]],
                        param_dtype=weights.DTYPES[s["param_dtype"]])
     weights.check_layout(conf, jax.eval_shape(
-        lambda: lm.init_params(cfg, jax.random.PRNGKey(0), rc)))
+        lambda: lm.init_params(cfg, jax.random.PRNGKey(0), rc)), root)
     if loadgen.max_request_len(cell.mix) > s["max_len"]:
         raise ValueError(f"{cell.name}: the mix sends requests longer "
                          f"than max_len {s['max_len']}")
-    params = jax.block_until_ready(weights.make_params(conf, seed))
+    params = jax.block_until_ready(weights.make_params(conf, seed, root))
     srv = drive.build_server(conf, cfg, params, rc)
     drive.warm(srv, loadgen.prompt_lens(cell.mix))
     return params, srv
@@ -125,7 +113,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              device: dict, peaks: dict, root: Path = ROOT,
              t_start: float = T_START):
     """One run of ``cell``: ``(result, checks)``."""
-    params, srv = setup(cell, seed)
+    params, srv = setup(cell, seed, root)
     trace_dir = None
     if trace:
         trace_dir = tempfile.mkdtemp(prefix="bench_trace_")

@@ -7,14 +7,20 @@ metric is a file of its own, found by name:
 * ``bench/traffic/<traffic>.json``: the mix's parameters;
 * ``bench/metrics/<metric>.py``: the metric's reader, a ``read(run)``
   that returns the number, or ``None`` where the run holds nothing to
-  read.
+  read;
+* ``bench/families/<family>.py``: the weights, plain reference, shape
+  check and counts of a configuration's model family, named by the
+  file's ``"family"`` (``"dense"`` where it names none); the functions
+  it defines are listed in ``bench/families/__init__.py``.
 
-So a later change adds a cell, a configuration, a mix or a metric with
-new files and new entries in ``BENCHMARK.json``, and edits none.
+So a later change adds a cell, a configuration of any family, a mix or
+a metric with new files and new entries in ``BENCHMARK.json``, and
+edits none.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -78,6 +84,29 @@ def reader(metric: str, root: Path = ROOT) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def family(conf: dict, root: Path = ROOT):
+    """The module ``bench/families/<family>.py`` of a configuration;
+    an unknown family is an error that names the file looked for."""
+    return _family(str(root), conf.get("family", "dense"))
+
+
+@functools.lru_cache(maxsize=None)
+def _family(root: str, name: str):
+    # loaded once a checkout: its jitted reference functions then
+    # compile once, and the counts are read at every step
+    path = Path(root) / "bench" / "families" / f"{name}.py"
+    if not path.is_file():
+        have = sorted(p.stem for p in path.parent.glob("*.py")
+                      if p.stem != "__init__")
+        raise KeyError(f"no family {name!r}: there is no {path}; the "
+                       f"families are {have}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_family_{name.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def peaks(kind: str, root: Path = ROOT) -> Dict[str, float]:
