@@ -52,6 +52,7 @@ from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from repro.configs.base import ArchConfig
 from repro.core.scheduler import Mode, Policy
@@ -124,15 +125,140 @@ def _cast_bytes(params, step_params) -> int:
                if a is not b)
 
 
+def _shapes(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=getattr(a, "sharding", None)),
+        tree)
+
+
+def _order(fn, p, cache):
+    """The dim order, major to minor, in which the compiler lays out
+    each leaf of the cache that ``fn(p, tokens, cache)`` takes and
+    returns, when the layout is left to it (``Layout.AUTO``).  Only
+    compiled, never run: the order is read from the program."""
+    auto = Format(Layout.AUTO)
+    probe = jax.jit(fn, in_shardings=(None, None, auto),
+                    out_shardings=(None, auto)).lower(
+        p, jax.ShapeDtypeStruct((1,), jnp.int32), _shapes(cache)).compile()
+    return {k: tuple(f.layout.major_to_minor)
+            for k, f in probe.input_formats[0][2].items()}
+
+
+def _permute(tree, order, inverse=False):
+    """``tree``'s leaves transposed into ``order`` (or back out of it);
+    a leaf already in its order is returned as it is."""
+    def one(k, a):
+        o = order[k]
+        if o == tuple(range(a.ndim)):
+            return a
+        return jnp.transpose(a, np.argsort(o) if inverse else o)
+    return {k: one(k, a) for k, a in tree.items()}
+
+
+class _Decode:
+    """``jit_mesc_decode``: one decode step that consumes the cache it
+    is given.  On an accelerator the cache is donated and each step
+    writes its K/V rows into it in place, so a caller must not use a
+    cache again once it has passed it in.  The CPU backend, where the
+    program is tested, keeps the caller's cache, so that a test may hand
+    a decode's input back as its output (the benchmark's stale-cache
+    fault, ``tests/bench/test_bench_faults.py``).
+
+    Where the cache lies in memory is the compiler's choice: ``bind``
+    compiles the step once with the cache's layout left to the compiler
+    (``_order``) and keeps the dim order it chose in ``order``.  The
+    cache the server holds is stored transposed into that order, so its
+    plain row-major layout is the one the compiler chose, and the step
+    transposes it back to (L, B, S, Hkv, Dh) inside the program, where
+    that costs no copy.  Every program boundary thus has the default
+    layout: an executable loaded from JAX's persistent compilation cache
+    loses a non-default output layout, and the next call would refuse
+    its own cache.  ``logical`` gives a stored cache's plain view."""
+
+    def __init__(self, fn):
+        self.__name__ = fn.__name__
+        self._fn = fn
+        self._jit = None
+        self.order = None
+
+    def bind(self, p, cache):
+        """Choose the stored order for a cache of the logical shapes of
+        ``cache``, once, for weights ``p``; the order.  The decode binds
+        itself to the first cache it meets where no prefill has bound it,
+        a cache that ``lm.init_cache`` made."""
+        if self.order is None:
+            order, fn = _order(self._fn, p, cache), self._fn
+            donate = next(iter(jax.tree_util.tree_leaves(p)[0].sharding
+                               .device_set)).platform != "cpu"
+
+            def mesc_decode(p, t, c):
+                logits, c = fn(p, t, _permute(c, order, inverse=True))
+                return logits, _permute(c, order)
+            self._jit = jax.jit(mesc_decode,
+                                donate_argnums=(2,) if donate else ())
+            self.order = order
+        return self.order
+
+    def stored(self, cache):
+        """A logical cache in the stored order."""
+        return _permute(cache, self.order)
+
+    def logical(self, cache):
+        """A stored cache's plain (L, B, S, Hkv, Dh) view."""
+        return _permute(cache, self.order, inverse=True)
+
+    def lower(self, p, t, c):
+        self.bind(p, c)
+        return self._jit.lower(p, t, c)
+
+    def __call__(self, p, t, c):
+        self.bind(p, c)
+        return self._jit(p, t, c)
+
+
+class _Prefill:
+    """``jit_mesc_prefill``, emitting its cache in the decode's stored
+    order, so that no request pays a relayout at its first decode."""
+
+    def __init__(self, fn, decode: _Decode):
+        self.__name__ = fn.__name__
+        self._fn, self._decode = fn, decode
+        self._jit = None
+
+    def _bound(self, p, b):
+        if self._jit is None:
+            # a one-token prompt's cache has the shapes of every cache
+            # that is decoded (a prompt of max_len or more is not)
+            one = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape[:1] + (1,)
+                                               + a.shape[2:], a.dtype), b)
+            fn = self._fn
+            order = self._decode.bind(p, jax.eval_shape(fn, p, one)[1])
+
+            def mesc_prefill(p, b):
+                logits, cache = fn(p, b)
+                return logits, _permute(cache, order)
+            self._jit = jax.jit(mesc_prefill)
+        return self._jit
+
+    def lower(self, p, b):
+        return self._bound(p, b).lower(p, b)
+
+    def __call__(self, p, b):
+        return self._bound(p, b)(p, b)
+
+
 def _programs(cfg: ArchConfig, rc: RuntimeConfig, max_len: int):
-    """The jitted ``(decode, prefill)`` pair, named so that XLA calls
-    the modules ``jit_mesc_decode`` and ``jit_mesc_prefill``."""
+    """The ``(decode, prefill)`` pair, named so that XLA calls the
+    modules ``jit_mesc_decode`` and ``jit_mesc_prefill``."""
     def mesc_decode(p, t, c):
         return lm.decode_step(cfg, p, t, c, rc)
 
     def mesc_prefill(p, b):
         return lm.prefill(cfg, p, b, rc, max_len=max_len)
-    return jax.jit(mesc_decode), jax.jit(mesc_prefill)
+    decode = _Decode(mesc_decode)
+    return decode, _Prefill(mesc_prefill, decode)
 
 
 class KVSlotArena:

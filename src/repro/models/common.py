@@ -21,7 +21,7 @@ class RuntimeConfig:
     z_loss: float = 1e-4
     logical_axes: bool = True           # emit sharding constraints
     cost_probe: bool = False            # unroll scans for exact HLO FLOP counts
-    dus_cache_update: bool = False      # decode cache write via DUS (vs select)
+    dus_cache_update: bool = False      # S-sharded cache: DUS write, not select
     pad_attn_heads: int = 0             # pad Q heads to this multiple for TP
 
 

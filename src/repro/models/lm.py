@@ -12,7 +12,8 @@ All entry points are pure functions of (cfg, params, ...):
   forward (train/prefill), loss_fn, init_cache, prefill, decode_step.
 
 Layer params are stacked along a leading scan dim; caches mirror that
-stacking so decode scans layers with (params, cache) as xs/ys.
+stacking, and decode scans layers with the params as xs and the caches
+as a carry.
 
 The dense block parts run under a ``jax.named_scope`` (``embed``,
 ``attn``, ``mlp``, ``logits``): metadata only, so that a device trace's
@@ -32,7 +33,7 @@ from repro.models import ffn as ffn_lib
 from repro.models import recurrent as rec_lib
 from repro.models.common import (RuntimeConfig, DEFAULT_RC, apply_norm,
                                  dense_init, norm_params, softmax_xent)
-from repro.runtime.sharding import shard_activation
+from repro.runtime.sharding import kv_seq_sharded, shard_activation
 
 Params = Dict[str, Any]
 
@@ -785,22 +786,52 @@ def prefill(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
 
 # --- per-family decode bodies ----------------------------------------------
 
+def _kv_write(c, row, i, pos, rc):
+    """Write ``row`` (B, Hkv, Dh) into the stacked cache ``c`` (L, B, S,
+    Hkv, Dh) at layer ``i``, position ``pos``: one row-sized update,
+    made in place when the caller donates the cache.  Where ``c`` is
+    sharded along S (``runtime.sharding.kv_seq_sharded``), an update at
+    a traced position on that dim makes GSPMD gather the cache, so there
+    the row goes in as a select over the layer's slice, which partitions
+    cleanly (``rc.dus_cache_update`` measures the update there too)."""
+    if kv_seq_sharded(c.shape) and not rc.dus_cache_update:
+        layer = jax.lax.dynamic_index_in_dim(c, i, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(
+            c, attn_lib.cache_update(layer, row, pos), i, axis=0)
+    return jax.lax.dynamic_update_slice(
+        c, row[None, :, None].astype(c.dtype), (i, 0, pos, 0, 0))
+
+
 @jax.named_scope("attn")
-def _attn_decode(cfg, rc, h, p, ck, cv, pos, positions, window=None):
+def _attn_decode(cfg, rc, h, p, ck, cv, i, pos, positions):
+    """Layer ``i``'s attention for one new token.  ``ck``/``cv`` are the
+    whole stacked (L, B, S, Hkv, Dh) caches the layer scan carries: the
+    new K/V row is written into them at (i, :, pos) and attention reads
+    their layer ``i``; no layer's slice is copied out and written back."""
+    x = apply_norm(cfg.norm, h, p["ln"])
+    q, k, v = attn_lib.gqa_project_qkv(x, p, cfg, positions)
+    ck = _kv_write(ck, k[:, 0], i, pos, rc)
+    cv = _kv_write(cv, v[:, 0], i, pos, rc)
+    o = attn_lib.decode_attention(
+        q[:, 0], jax.lax.dynamic_index_in_dim(ck, i, keepdims=False),
+        jax.lax.dynamic_index_in_dim(cv, i, keepdims=False), pos)
+    o = o.reshape(o.shape[0], 1, -1)
+    h = h + jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(o.dtype))
+    return h, ck, cv
+
+
+@jax.named_scope("attn")
+def _window_attn_decode(cfg, rc, h, p, ck, cv, pos, positions):
+    """Local attention for one new token over one layer's ring cache
+    ``ck``/``cv`` (B, W, Hkv, Dh): the row goes to slot ``pos % W``."""
     x = apply_norm(cfg.norm, h, p["ln"])
     q, k, v = attn_lib.gqa_project_qkv(x, p, cfg, positions)
     dus = rc.dus_cache_update
-    if window is not None:
-        W = ck.shape[1]
-        slot = pos % W
-        ck = attn_lib.cache_update(ck, k[:, 0], slot, use_dus=dus)
-        cv = attn_lib.cache_update(cv, v[:, 0], slot, use_dus=dus)
-        pos_eff = jnp.minimum(pos, W - 1)
-    else:
-        ck = attn_lib.cache_update(ck, k[:, 0], pos, use_dus=dus)
-        cv = attn_lib.cache_update(cv, v[:, 0], pos, use_dus=dus)
-        pos_eff = pos
-    o = attn_lib.decode_attention(q[:, 0], ck, cv, pos_eff)
+    W = ck.shape[1]
+    slot = pos % W
+    ck = attn_lib.cache_update(ck, k[:, 0], slot, use_dus=dus)
+    cv = attn_lib.cache_update(cv, v[:, 0], slot, use_dus=dus)
+    o = attn_lib.decode_attention(q[:, 0], ck, cv, jnp.minimum(pos, W - 1))
     o = o.reshape(o.shape[0], 1, -1)
     h = h + jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(o.dtype))
     return h, ck, cv
@@ -840,30 +871,40 @@ def _mlstm_decode(cfg, rc, h, p, state, conv):
     return h + out[:, None], state, conv
 
 
-def _scan_layers_carry(body_kv, h, blocks, cache, keys, rc):
-    """Layer scan with the decode cache as a *carry* (not xs/ys).
+def _scan_layers(body, h, blocks, cache, keys, rc):
+    """Layer scan with the decode caches ``keys`` as a *carry* (not
+    xs/ys), whole, with the layer's index.
 
     xs/ys buffers cannot alias in XLA while-loops, which would double the
-    multi-GB KV cache; carries alias in place, and the per-layer index /
-    update on the (unsharded) leading layer dim partitions cleanly.
-    body_kv(h, p, layer_cache) -> (h, new_layer_cache).
+    multi-GB KV cache; carries alias in place.
+    body(h, p, caches, i) -> (h, caches).
     """
-    sub = {k: cache[k] for k in keys}
-
-    def body(carry, p):
+    def step(carry, p):
         h, caches, i = carry
-        layer = {k: jax.lax.dynamic_index_in_dim(v, i, axis=0, keepdims=False)
-                 for k, v in caches.items()}
-        h, newl = body_kv(h, p, layer)
-        caches = {k: jax.lax.dynamic_update_index_in_dim(
-            caches[k], newl[k].astype(caches[k].dtype), i, axis=0)
-            for k in caches}
+        h, caches = body(h, p, caches, i)
         return (h, caches, i + 1), None
 
     (h, caches, _), _ = jax.lax.scan(
-        body, (h, sub, jnp.zeros((), jnp.int32)), blocks,
-        unroll=rc.cost_probe)
+        step, (h, {k: cache[k] for k in keys}, jnp.zeros((), jnp.int32)),
+        blocks, unroll=rc.cost_probe)
     return h, caches
+
+
+def _scan_layers_carry(body_kv, h, blocks, cache, keys, rc):
+    """``_scan_layers`` for bodies that take one layer's slice of each
+    cache and return it whole; the per-layer index / update on the
+    (unsharded) leading layer dim partitions cleanly.
+    body_kv(h, p, layer_cache) -> (h, new_layer_cache).
+    """
+    def body(h, p, caches, i):
+        layer = {k: jax.lax.dynamic_index_in_dim(v, i, axis=0, keepdims=False)
+                 for k, v in caches.items()}
+        h, newl = body_kv(h, p, layer)
+        return h, {k: jax.lax.dynamic_update_index_in_dim(
+            caches[k], newl[k].astype(caches[k].dtype), i, axis=0)
+            for k in caches}
+
+    return _scan_layers(body, h, blocks, cache, keys, rc)
 
 
 def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
@@ -881,26 +922,26 @@ def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
     new_cache = {}
 
     if fam in ("dense", "vlm", "audio"):
-        def body(h, p, c):
+        def body(h, p, c, i):
             h, ck, cv = _attn_decode(cfg, rc, h, p["attn"], c["ck"], c["cv"],
-                                     pos, positions)
+                                     i, pos, positions)
             h = _mlp_full(cfg, rc, h, p["mlp"])
             return h, {"ck": ck, "cv": cv}
-        h, kv = _scan_layers_carry(body, h, blocks, cache, ("ck", "cv"), rc)
+        h, kv = _scan_layers(body, h, blocks, cache, ("ck", "cv"), rc)
         new_cache.update(kv)
     elif fam == "moe":
-        def body(h, p, c):
+        def body(h, p, c, i):
             h, cka, cva = _attn_decode(cfg, rc, h, p["attn_a"], c["cka"],
-                                       c["cva"], pos, positions)
+                                       c["cva"], i, pos, positions)
             h = _mlp_full(cfg, rc, h, p["mlp"])
             h, ckb, cvb = _attn_decode(cfg, rc, h, p["attn_b"], c["ckb"],
-                                       c["cvb"], pos, positions)
+                                       c["cvb"], i, pos, positions)
             x = apply_norm(cfg.norm, h, p["moe"]["ln"])
             y, _ = ffn_lib.moe_apply(x.reshape(1, B, -1), p["moe"], cfg)
             h = h + y.reshape(B, 1, -1)
             return h, {"cka": cka, "cva": cva, "ckb": ckb, "cvb": cvb}
-        h, kv = _scan_layers_carry(body, h, blocks, cache,
-                                   ("cka", "cva", "ckb", "cvb"), rc)
+        h, kv = _scan_layers(body, h, blocks, cache,
+                             ("cka", "cva", "ckb", "cvb"), rc)
         new_cache.update(kv)
     elif fam == "mla_moe":
         def body(h, p, c):
@@ -915,8 +956,6 @@ def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
         h, kv = _scan_layers_carry(body, h, blocks, cache, ("cc", "ckr"), rc)
         new_cache.update(kv)
     elif fam == "hybrid":
-        w = cfg.rglru.window
-
         def body(h, p, c):
             h, rh0, rcv0 = _rglru_decode(cfg, rc, h, p["rec0"], c["rh0"],
                                          c["rconv0"])
@@ -924,8 +963,8 @@ def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
             h, rh1, rcv1 = _rglru_decode(cfg, rc, h, p["rec1"], c["rh1"],
                                          c["rconv1"])
             h = _mlp_full(cfg, rc, h, p["mlp1"], act="gelu")
-            h, wk, wv = _attn_decode(cfg, rc, h, p["attn"], c["wk"], c["wv"],
-                                     pos, positions, window=w)
+            h, wk, wv = _window_attn_decode(cfg, rc, h, p["attn"], c["wk"],
+                                            c["wv"], pos, positions)
             h = _mlp_full(cfg, rc, h, p["mlp2"], act="gelu")
             return h, {"rh0": rh0, "rconv0": rcv0, "rh1": rh1, "rconv1": rcv1,
                        "wk": wk, "wv": wv}

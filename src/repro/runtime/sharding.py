@@ -274,6 +274,16 @@ def cache_specs(cache, rules: AxisRules):
         lambda p, a: _cache_spec(p, a, rules), cache)
 
 
+def kv_seq_sharded(shape: Tuple[int, ...]) -> bool:
+    """Whether ``cache_specs`` under the current rules splits the S dim
+    of a KV cache of ``shape`` (..., B, S, Hkv, Dh) over devices."""
+    r = current_rules()
+    if r is None or r.tp is None:
+        return False
+    seq = r.sanitize(_CACHE_RULES["ck"][1:2], shape[-3:-2])[0]
+    return seq is not None and r.axis_size(seq) > 1
+
+
 def batch_specs(batch, rules: AxisRules):
     dp = rules.dp if len(rules.dp) != 1 else rules.dp[0]
     return jax.tree_util.tree_map(

@@ -169,3 +169,87 @@ def test_jit_engine_lockstep_compiles_under_x64(one_chip):
     text = compiled.as_text()
     assert "while" in text
     assert sj.while_body_kernels(text) > 0
+
+
+def _hlo_ops(text):
+    """``{name: (dtype, dims, opcode, operands, called)}`` for every
+    array-valued instruction, and ``{computation: root name}``."""
+    import re
+    ops, roots, comp = {}, {}, None
+    head = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+    inst = re.compile(r"^\s*(ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
+                      r"([\w-]+)\(([^)]*)\)(.*)$")
+    for ln in text.splitlines():
+        m = head.match(ln)
+        if m:
+            comp = m.group(1)
+            continue
+        m = inst.match(ln)
+        if m:
+            root, name, dt, dims, op, args, rest = m.groups()
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            called = re.search(r"calls=%(\S+?)[,\s]", rest + " ")
+            ops[name] = (dt, dims, op, re.findall(r"%([^\s,]+)", args),
+                         called and called.group(1))
+            if root:
+                roots[comp] = name
+    return ops, roots
+
+
+@pytest.mark.parametrize("arch,max_len,param_dtype",
+                         [("olmo-1b", 2048, jnp.float32),
+                          ("phi4-mini-3.8b", 8192, jnp.bfloat16)],
+                         ids=["olmo-1b", "phi4-mini-3.8b"])
+def test_mesc_decode_writes_its_rows_in_place(one_chip, arch, max_len,
+                                              param_dtype):
+    """``jit_mesc_decode`` as ``_programs`` builds it, at the benchmark's
+    program shapes, on a cache in the stored order the compiler chose:
+    the donated cache is aliased whole to the output, no op but the row
+    update makes an array of the whole cache's size (no copy, no
+    relayout), every such update writes one row, no select runs over a
+    layer's slice of the cache, and the cache goes in and out in its
+    plain layout (a non-default one is lost when the executable comes
+    back from JAX's persistent compilation cache)."""
+    import math
+    from repro.configs import get_config
+    from repro.core.serving import _programs
+    from repro.models import lm
+    from repro.models.common import RuntimeConfig
+    cfg = get_config(arch)
+    rc = RuntimeConfig(param_dtype=param_dtype)
+    params = jax.eval_shape(lambda: lm.step_params(
+        cfg, lm.init_params(cfg, jax.random.PRNGKey(0), rc), rc))
+    cache = jax.eval_shape(lambda: lm.init_cache(cfg, 1, max_len, rc))
+    decode, _ = _programs(cfg, rc, max_len)
+    decode.bind(_shapes(params, one_chip), _shapes(cache, one_chip))
+    compiled = decode.lower(
+        _shapes(params, one_chip), jax.ShapeDtypeStruct((1,), jnp.int32),
+        _shapes(jax.eval_shape(decode.stored, cache), one_chip)).compile()
+    L, B, S, H, D = cache["ck"].shape
+    whole, layer, row = L * B * S * H * D, B * S * H * D, B * H * D
+    ops, roots = _hlo_ops(compiled.as_text())
+    size = {n: math.prod(o[1]) for n, o in ops.items()}
+
+    def row_update(name):
+        dt, _, op, args, called = ops[name]
+        if op == "fusion":
+            return row_update(roots[called])
+        return op == "dynamic-update-slice" and size[args[1]] == row
+
+    big = {n: o[2] for n, o in ops.items()
+           if o[0] == "bf16" and size[n] == whole}
+    moved = {n: op for n, op in big.items()
+             if op not in ("parameter", "get-tuple-element", "bitcast")
+             and not row_update(n)}
+    assert sum(row_update(n) for n in big) >= 2            # K and V
+    assert moved == {}
+    assert [n for n, o in ops.items()
+            if o[2] == "select" and size[n] == layer] == []
+    formats = [compiled.input_formats[0][2], compiled.output_formats[1]]
+    assert all(f.layout.major_to_minor == tuple(range(len(a.shape)))
+               for c in formats for f, a in zip(
+                   jax.tree.leaves(c), jax.tree.leaves(cache)))
+    mem = compiled.memory_analysis()
+    kv = 2 * whole * 2
+    assert kv <= mem.alias_size_in_bytes < kv + 1024       # + pos
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
