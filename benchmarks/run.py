@@ -61,8 +61,7 @@ def main() -> None:
                     help="paper-scale experiment sizes (1000 task sets)")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset (fig2,fig6,fig7,fig8,"
-                         "fig9,fig10,fig11,fig12,fig13,overhead,"
-                         "roofline)")
+                         "fig9,fig10,fig11,fig12,fig13,overhead)")
     ap.add_argument("--workers", type=int, default=None,
                     help="worker processes per campaign "
                          "(default: CPU count / $REPRO_WORKERS)")
@@ -112,7 +111,7 @@ def main() -> None:
                             fig7_blocking, fig8_success, fig9_hi_success,
                             fig10_survivability, fig11_multiacc,
                             fig12_serving_slo, fig13_fault_survivability,
-                            tbl_overhead, roofline)
+                            tbl_overhead)
     table = {
         "fig2": fig2_instruction_costs.main,
         "fig6": fig6_banks.main,
@@ -124,7 +123,6 @@ def main() -> None:
         "fig12": fig12_serving_slo.main,
         "fig13": fig13_fault_survivability.main,
         "overhead": tbl_overhead.main,
-        "roofline": roofline.main,
     }
     # sim figures that take a scenario axis (the rest are scenario-free
     # analyses; --scenario leaves them untouched)

@@ -2,9 +2,7 @@
 from __future__ import annotations
 
 from repro.configs.base import (ArchConfig, MLAConfig, MoEConfig, RGLRUConfig,
-                                ShapeConfig, XLSTMConfig, SHAPES,
-                                SHAPES_BY_NAME, TRAIN_4K, PREFILL_32K,
-                                DECODE_32K, LONG_500K, supports_shape)
+                                XLSTMConfig)
 
 from repro.configs.llama4_maverick_400b_a17b import CONFIG as LLAMA4_MAVERICK
 from repro.configs.deepseek_v2_lite_16b import CONFIG as DEEPSEEK_V2_LITE
@@ -38,7 +36,5 @@ def list_archs():
 
 __all__ = [
     "ArchConfig", "MLAConfig", "MoEConfig", "RGLRUConfig", "XLSTMConfig",
-    "ShapeConfig", "SHAPES", "SHAPES_BY_NAME", "TRAIN_4K", "PREFILL_32K",
-    "DECODE_32K", "LONG_500K", "supports_shape", "ARCHS", "get_config",
-    "list_archs",
+    "ARCHS", "get_config", "list_archs",
 ]

@@ -1,9 +1,7 @@
-"""Architecture / shape configuration system.
+"""Architecture configuration system.
 
-Every assigned architecture is an :class:`ArchConfig`; every workload shape is
-a :class:`ShapeConfig`.  ``(arch, shape)`` pairs form the dry-run / roofline
-cells.  Reduced (smoke) configs are derived mechanically so every family has a
-CPU-runnable variant.
+Every assigned architecture is an :class:`ArchConfig`.  Reduced (smoke)
+configs are derived mechanically so every family has a CPU-runnable variant.
 """
 from __future__ import annotations
 
@@ -68,7 +66,7 @@ class ArchConfig:
     mla: Optional[MLAConfig] = None
     rglru: Optional[RGLRUConfig] = None
     xlstm: Optional[XLSTMConfig] = None
-    # modality frontends (stubs — precomputed embeddings via input_specs)
+    # modality frontends (stubs — precomputed embeddings in the batch)
     n_frontend_tokens: int = 0     # vlm: image patch embeds prepended
     n_codebooks: int = 1           # audio: EnCodec codebooks (summed embeds)
     source: str = ""
@@ -76,11 +74,6 @@ class ArchConfig:
     @property
     def dh(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
-
-    @property
-    def sub_quadratic(self) -> bool:
-        """True if the arch supports O(1)-state decode (may run long_500k)."""
-        return self.family in ("hybrid", "xlstm")
 
     def param_count(self) -> int:
         """Approximate total parameter count (embeddings + blocks)."""
@@ -160,31 +153,3 @@ def _pattern_for(cfg: ArchConfig):
         return ["attn"] * cfg.n_layers
     pat = cfg.rglru.block_pattern
     return [pat[i % len(pat)] for i in range(cfg.n_layers)]
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                      # train | prefill | decode
-
-    @property
-    def tokens(self) -> int:
-        return self.seq_len * self.global_batch
-
-
-TRAIN_4K = ShapeConfig("train_4k", 4096, 256, "train")
-PREFILL_32K = ShapeConfig("prefill_32k", 32768, 32, "prefill")
-DECODE_32K = ShapeConfig("decode_32k", 32768, 128, "decode")
-LONG_500K = ShapeConfig("long_500k", 524288, 1, "decode")
-
-SHAPES = (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
-SHAPES_BY_NAME = {s.name: s for s in SHAPES}
-
-
-def supports_shape(arch: ArchConfig, shape: ShapeConfig) -> bool:
-    """long_500k needs sub-quadratic attention."""
-    if shape.name == "long_500k":
-        return arch.sub_quadratic
-    return True

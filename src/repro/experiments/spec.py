@@ -13,9 +13,9 @@ own.  Two sweep flavours cover the repo's experiments:
     which makes every point reproducible in isolation and keeps the
     engine's output bit-identical to the legacy serial loops.
   * :class:`FuncSweep` — analysis fan-outs (per-workload instruction
-    statistics, roofline cells, ...): each point calls a module-level
-    function referenced as ``"package.module:function"`` with
-    JSON-able kwargs.
+    statistics, multi-accelerator and serving points, ...): each point
+    calls a module-level function referenced as
+    ``"package.module:function"`` with JSON-able kwargs.
 
 Every point owns a stable content hash (:func:`canonical_hash` over its
 canonical-JSON form) used as its result-cache key, and every sweep owns
@@ -279,7 +279,7 @@ class FuncSweep:
     """Fan-out of one importable function over a list of kwargs dicts.
 
     ``cache=False`` marks sweeps whose points read mutable filesystem
-    state (e.g. roofline over dry-run artifacts) — they always re-run.
+    state — they always re-run.
     """
     name: str
     fn: str

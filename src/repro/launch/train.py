@@ -1,7 +1,7 @@
-"""Training driver: data pipeline -> sharded train_step -> checkpoints.
+"""Training driver: data pipeline -> train_step -> checkpoints.
 
-Runs at smoke scale on CPU and is the same code path the production mesh
-uses (pass --mesh prod inside a 256-device environment).
+Runs at smoke scale on the CPU, and on one accelerator at any size that
+fits it.
 
   PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b-smoke \
       --steps 200 --batch 8 --seq 64 --ckpt-dir /tmp/ckpt

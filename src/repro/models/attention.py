@@ -25,8 +25,7 @@ def _split_heads(x, n_heads, dh):
 # ---------------------------------------------------------------------------
 
 def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,
-                    block_q: int = 512, block_kv: int = 512, softcap=None,
-                    unroll: bool = False):
+                    block_q: int = 512, block_kv: int = 512, softcap=None):
     """q (B,Sq,Hq,Dh), k/v (B,Skv,Hkv,Dh) -> (B,Sq,Hq,Dh).
 
     Blocked online-softmax; GQA via head grouping.  KV blocks are scanned with
@@ -83,8 +82,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,
         a0 = jnp.zeros((B, Hq, bq, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0),
-            (jnp.arange(nkv), jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0)),
-            unroll=unroll)
+            (jnp.arange(nkv), jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0)))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return out  # (B, Hq, bq, Dv)
 
@@ -98,15 +96,13 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,
         return None, q_block_ckpt(qi, i, kb, vb)
 
     _, outs = jax.lax.scan(outer, None,
-                           (jnp.arange(nq), jnp.moveaxis(qb, 1, 0)),
-                           unroll=unroll)
+                           (jnp.arange(nq), jnp.moveaxis(qb, 1, 0)))
     # (nq, B, Hq, bq, Dv) -> (B, nq, bq, Hq, Dv) -> (B, Sq, Hq, Dv)
     out = jnp.moveaxis(outs, 0, 1).transpose(0, 1, 3, 2, 4)
     return out.reshape(B, Sq, Hq, Dv).astype(q.dtype)
 
 
-def local_attention(q, k, v, *, window: int, q_offset=0, block_q: int = 512,
-                    unroll: bool = False):
+def local_attention(q, k, v, *, window: int, q_offset=0, block_q: int = 512):
     """Banded causal attention: each query attends the previous ``window``
     keys (inclusive of self).  Exact-FLOP banded gather — O(S * window).
 
@@ -155,8 +151,7 @@ def local_attention(q, k, v, *, window: int, q_offset=0, block_q: int = 512,
         return None, q_block_ckpt(qi, i, kp, vp)
 
     _, outs = jax.lax.scan(outer, None,
-                           (jnp.arange(nq), jnp.moveaxis(qb, 1, 0)),
-                           unroll=unroll)
+                           (jnp.arange(nq), jnp.moveaxis(qb, 1, 0)))
     # (nq, B, Hq, bq, Dh) -> (B, Sq, Hq, Dh)
     out = jnp.moveaxis(outs, 0, 1).transpose(0, 1, 3, 2, 4)
     return out.reshape(B, Sq, Hq, Dh).astype(q.dtype)
@@ -169,8 +164,7 @@ def local_attention(q, k, v, *, window: int, q_offset=0, block_q: int = 512,
 def decode_attention(q, k_cache, v_cache, pos):
     """q (B,Hq,Dh); k/v_cache (B,S,Hkv,Dh); pos () current position.
 
-    Memory-bound; the softmax reductions partition over an S-sharded cache
-    (flash-decoding emerges from GSPMD).  Positions > pos are masked.
+    Memory-bound; positions > pos are masked.
     """
     B, Hq, Dh = q.shape
     _, S, Hkv, _ = k_cache.shape
@@ -186,18 +180,9 @@ def decode_attention(q, k_cache, v_cache, pos):
     return o.reshape(B, Hq, Dh).astype(q.dtype)
 
 
-def cache_update(cache, new, pos, use_dus: bool = False):
-    """Write ``new`` (B, Hkv, Dh) into cache (B, S, Hkv, Dh) at ``pos``.
-
-    Default: one-hot select — DUS on a sharded S dim makes GSPMD replicate
-    the whole cache, while the select partitions cleanly (each shard
-    touches only its S-slice) at the cost of a full cache read+write in
-    the XLA byte model.  ``use_dus`` measures the alternative (SSPerf);
-    the fused Pallas decode kernel removes the extra traffic on TPU.
-    """
-    if use_dus:
-        return jax.lax.dynamic_update_slice_in_dim(
-            cache, new[:, None].astype(cache.dtype), pos, axis=1)
+def cache_update(cache, new, pos):
+    """Write ``new`` (B, Hkv, Dh) into cache (B, S, Hkv, Dh) at ``pos``,
+    as a one-hot select over the whole cache (the window ring's write)."""
     S = cache.shape[1]
     hit = (jnp.arange(S) == pos)[None, :, None, None]
     return jnp.where(hit, new[:, None].astype(cache.dtype), cache)

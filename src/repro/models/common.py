@@ -10,32 +10,13 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class RuntimeConfig:
-    """Numerics / memory policy knobs (perf levers for §Perf)."""
+    """The dtypes the weights are held in and computed in."""
     compute_dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
-    remat_policy: str = "none"          # none | full | dots
-    remat_groups: int = 0               # >0: nested-scan double remat, G groups
-    sequence_parallel: bool = False     # shard residual-stream S over 'model'
-    flash_block_q: int = 512
-    flash_block_kv: int = 512
-    z_loss: float = 1e-4
-    logical_axes: bool = True           # emit sharding constraints
-    cost_probe: bool = False            # unroll scans for exact HLO FLOP counts
-    dus_cache_update: bool = False      # S-sharded cache: DUS write, not select
-    pad_attn_heads: int = 0             # pad Q heads to this multiple for TP
 
 
 DEFAULT_RC = RuntimeConfig()
 CPU_RC = RuntimeConfig(compute_dtype=jnp.float32)
-
-
-def remat_wrap(fn, rc: RuntimeConfig):
-    if rc.remat_policy == "none":
-        return fn
-    if rc.remat_policy == "dots":
-        policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-        return jax.checkpoint(fn, policy=policy)
-    return jax.checkpoint(fn)
 
 
 # ---------------------------------------------------------------------------

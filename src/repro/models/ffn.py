@@ -2,13 +2,12 @@
 
 MoE uses sort-based capacity dispatch (no (N,E,C) one-hot tensor):
 tokens are argsorted by expert id, packed into per-expert buffers of capacity
-C = ceil(N*k*cf/E) via gathers, processed with batched expert einsums (expert
-dim sharded over 'model' = EP), and combined with a batched scatter-add
-(lowers to local scatter + all-reduce over the expert axis under GSPMD).
+C = ceil(N*k*cf/E) via gathers, processed with batched expert einsums, and
+combined with a gather of each token's expert outputs.
 
-Routing rows: training/prefill routes per sequence (rows=B, tokens=S) so the
-sort stays local to each data shard; decode routes over the batch (rows=1,
-tokens=B) so capacity stays proportional to live tokens.
+Routing rows: training/prefill routes per sequence (rows=B, tokens=S);
+decode routes over the batch (rows=1, tokens=B) so capacity stays
+proportional to live tokens.
 """
 from __future__ import annotations
 
@@ -17,23 +16,12 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.runtime.sharding import shard_activation
-
 
 def _glu(x, p, act):
-    if x.ndim == 3:
-        x = shard_activation(x, "ffn_in", None)
     h = jnp.einsum("...d,df->...f", x, p["w1"].astype(x.dtype))
     g = jnp.einsum("...d,df->...f", x, p["w3"].astype(x.dtype))
     h = act(h) * g
-    if h.ndim == 3:
-        h = shard_activation(h, "ffn_hidden", None)
-    y = jnp.einsum("...f,fd->...d", h, p["w2"].astype(x.dtype))
-    if y.ndim == 3:
-        # partial sums over 'model' reduce-scatter straight into the
-        # S-sharded residual layout (Megatron-SP exit boundary)
-        y = shard_activation(y, "residual", None)
-    return y
+    return jnp.einsum("...f,fd->...d", h, p["w2"].astype(x.dtype))
 
 
 def swiglu(x, p):
@@ -61,7 +49,6 @@ def moe_apply(x, p, cfg):
     E, K = e.num_experts, e.top_k
     C = moe_capacity(N, K, E, e.capacity_factor)
 
-    x = shard_activation(x, "moe_tokens", None)
     router_logits = jnp.einsum("rnd,de->rne", x, p["router"].astype(x.dtype))
     router_logits = router_logits.astype(jnp.float32)
     probs = jax.nn.softmax(router_logits, axis=-1)
@@ -98,10 +85,8 @@ def moe_apply(x, p, cfg):
     x_e = jnp.take_along_axis(x, src_tok.reshape(R, E * C)[..., None],
                               axis=1).reshape(R, E, C, D)
     x_e = x_e * slot_valid[..., None].astype(x.dtype)
-    x_e = shard_activation(x_e, "moe_buf", None)                 # EP layout
     h = jnp.einsum("recd,edf->recf", x_e, p["w1"].astype(x.dtype))
     g = jnp.einsum("recd,edf->recf", x_e, p["w3"].astype(x.dtype))
-    h = shard_activation(h, "moe_buf", None)
     y_e = jnp.einsum("recf,efd->recd", jax.nn.silu(h) * g,
                      p["w2"].astype(x.dtype))
     y_e = y_e * slot_gate[..., None]
@@ -111,13 +96,11 @@ def moe_apply(x, p, cfg):
     pos_unsorted = jnp.take_along_axis(pos_in_e, inv, axis=-1)
     slot_c2 = pos_unsorted.reshape(R, N, K)
     valid_tok = (slot_c2 < C)
-    y_e = shard_activation(y_e, "moe_gathered", None)  # AG experts locally
     flat_idx = (eidx * C + jnp.clip(slot_c2, 0, C - 1)).reshape(R, N * K)
     picked = jnp.take_along_axis(y_e.reshape(R, E * C, D),
                                  flat_idx[..., None], axis=1)
     picked = picked.reshape(R, N, K, D)                # gated expert outputs
     y = jnp.sum(picked * valid_tok[..., None].astype(x.dtype), axis=2)
-    y = shard_activation(y, "moe_tokens", None)
 
     if e.num_shared > 0:
         y = y + swiglu(x, p["shared"])

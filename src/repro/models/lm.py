@@ -32,8 +32,7 @@ from repro.models import attention as attn_lib
 from repro.models import ffn as ffn_lib
 from repro.models import recurrent as rec_lib
 from repro.models.common import (RuntimeConfig, DEFAULT_RC, apply_norm,
-                                 dense_init, norm_params, softmax_xent)
-from repro.runtime.sharding import kv_seq_sharded, shard_activation
+                                 dense_init, norm_params, softmax_xent_sums)
 
 Params = Dict[str, Any]
 
@@ -346,46 +345,15 @@ def lm_logits(cfg: ArchConfig, params: Params, h, rc: RuntimeConfig):
 # ===========================================================================
 
 @jax.named_scope("attn")
-def _attn_full(cfg, rc, h, p, positions, *, window=None, make_cache=False):
+def _attn_full(cfg, h, p, positions, *, window=None, make_cache=False):
     x = apply_norm(cfg.norm, h, p["ln"])
     q, k, v = attn_lib.gqa_project_qkv(x, p, cfg, positions)
-    # head padding: archs whose Q-head count does not divide the TP axis
-    # (40/56/24/10 on a 16-way axis) would otherwise replicate attention
-    # across 'model'.  Padding is PER KV GROUP (so GQA head->kv alignment
-    # is preserved) and exact: padded heads are sliced off before the
-    # output projection, costing +pad/H extra FLOPs.
-    g_orig = g_pad = 0
-    if rc.pad_attn_heads > 1 and q.shape[2] % rc.pad_attn_heads != 0:
-        B_, S_, Hq_, dh_ = q.shape
-        Hkv_ = cfg.n_kv_heads
-        g_orig = Hq_ // Hkv_
-        g_pad = g_orig
-        while (Hkv_ * g_pad) % rc.pad_attn_heads != 0:
-            g_pad += 1
-        qg = q.reshape(B_, S_, Hkv_, g_orig, dh_)
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, g_pad - g_orig),
-                          (0, 0)))
-        q = qg.reshape(B_, S_, Hkv_ * g_pad, dh_)
-    q = shard_activation(q, "attn_in", rc)
-    k = shard_activation(k, "attn_in", rc)
-    v = shard_activation(v, "attn_in", rc)
     if window is not None:
-        o = attn_lib.local_attention(q, k, v, window=window,
-                                     block_q=rc.flash_block_q,
-                                     unroll=rc.cost_probe)
+        o = attn_lib.local_attention(q, k, v, window=window)
     else:
-        o = attn_lib.flash_attention(q, k, v, causal=True,
-                                     block_q=rc.flash_block_q,
-                                     block_kv=rc.flash_block_kv,
-                                     unroll=rc.cost_probe)
-    if g_pad and g_pad != g_orig:          # drop padded heads (exact)
-        B_, S_ = o.shape[:2]
-        o = o.reshape(B_, S_, cfg.n_kv_heads, g_pad, -1)[:, :, :, :g_orig]
-        o = o.reshape(B_, S_, cfg.n_heads, -1)
+        o = attn_lib.flash_attention(q, k, v, causal=True)
     o = o.reshape(o.shape[:2] + (-1,))
-    o = shard_activation(o, "attn_out", rc)
-    delta = jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(o.dtype))
-    h = h + shard_activation(delta, "residual", rc)
+    h = h + jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(o.dtype))
     cache = None
     if make_cache:
         if window is not None:
@@ -403,26 +371,20 @@ def _attn_full(cfg, rc, h, p, positions, *, window=None, make_cache=False):
     return h, cache
 
 
-def _mla_full(cfg, rc, h, p, positions, *, make_cache=False):
+def _mla_full(cfg, h, p, positions, *, make_cache=False):
     x = apply_norm(cfg.norm, h, p["ln"])
     q, k, v, c, kr = attn_lib.mla_prefill_qkv(x, p, cfg, positions)
-    q = shard_activation(q, "attn_in", rc)
-    k = shard_activation(k, "attn_in", rc)
-    v = shard_activation(v, "attn_in", rc)
-    o = attn_lib.flash_attention(q, k, v, causal=True,
-                                 block_q=rc.flash_block_q,
-                                 block_kv=rc.flash_block_kv,
-                                 unroll=rc.cost_probe)
+    o = attn_lib.flash_attention(q, k, v, causal=True)
     m = cfg.mla
     o = jnp.einsum("bshv,hvd->bsd", o,
                    p["w_o"].astype(o.dtype).reshape(
                        cfg.n_heads, m.v_head_dim, -1))
-    h = h + shard_activation(o, "residual", rc)
+    h = h + o
     return h, ((c, kr) if make_cache else None)
 
 
 @jax.named_scope("mlp")
-def _mlp_full(cfg, rc, h, p, *, act="swiglu"):
+def _mlp_full(cfg, h, p, *, act="swiglu"):
     x = apply_norm(cfg.norm, h, p["ln"])
     y = ffn_lib.swiglu(x, p) if act == "swiglu" else ffn_lib.geglu(x, p)
     return h + y
@@ -437,15 +399,15 @@ def _moe_nometrics(cfg, h, p):
     return h + y
 
 
-def _moe_full(cfg, rc, h, p, aux):
+def _moe_full(cfg, h, p, aux):
     """Returns (h, aux) with per-layer MoE metrics accumulated into ``aux``."""
     x = apply_norm(cfg.norm, h, p["ln"])
     y, metrics = ffn_lib.moe_apply(x, p, cfg)
     aux = {k: aux[k] + metrics[k] for k in MOE_METRIC_KEYS}
-    return h + shard_activation(y, "residual", rc), aux
+    return h + y, aux
 
 
-def _rglru_full(cfg, rc, h, p, *, h0=None, conv0=None, make_cache=False):
+def _rglru_full(cfg, h, p, *, h0=None, conv0=None, make_cache=False):
     r = cfg.rglru
     x = apply_norm(cfg.norm, h, p["ln"])
     y = jax.nn.gelu(jnp.einsum("bsd,dr->bsr", x, p["w_y"].astype(x.dtype)))
@@ -455,7 +417,7 @@ def _rglru_full(cfg, rc, h, p, *, h0=None, conv0=None, make_cache=False):
     rec, h_last = rec_lib.rglru_scan(xb, p, cfg.n_heads, h0=h0)
     out = jnp.einsum("bsr,rd->bsd", rec * y, p["w_out"].astype(x.dtype))
     cache = (h_last, conv_state) if make_cache else None
-    return h + shard_activation(out, "residual", rc), cache
+    return h + out, cache
 
 
 def _mlstm_qkv(cfg, p, x):
@@ -479,15 +441,14 @@ def _mlstm_qkv(cfg, p, x):
         log_f.transpose(0, 2, 1), z, conv_state
 
 
-def _mlstm_full(cfg, rc, h, p, *, state=None, make_cache=False):
+def _mlstm_full(cfg, h, p, *, state=None, make_cache=False):
     x = apply_norm(cfg.norm, h, p["ln"])
     q, k, v, log_i, log_f, z, conv_state = _mlstm_qkv(cfg, p, x)
     S = q.shape[2]
     chunk = cfg.xlstm.chunk
     if S > chunk and S % chunk == 0:
         hh, new_state = rec_lib.mlstm_chunkwise(q, k, v, log_i, log_f,
-                                                chunk=chunk, state=state,
-                                                unroll=rc.cost_probe)
+                                                chunk=chunk, state=state)
     else:
         hh = rec_lib.mlstm_parallel(q, k, v, log_i, log_f)
         new_state = rec_lib.mlstm_final_state(q, k, v, log_i, log_f, state) \
@@ -497,11 +458,10 @@ def _mlstm_full(cfg, rc, h, p, *, state=None, make_cache=False):
     hh = rec_lib.groupnorm_heads(hh, p["gn"], H)
     out = jnp.einsum("bse,ed->bsd", hh * jax.nn.silu(z),
                      p["w_down"].astype(h.dtype))
-    out = shard_activation(out, "residual", rc)
     return h + out, ((new_state, conv_state) if make_cache else None)
 
 
-def _slstm_full(cfg, rc, h, p, *, state=None, make_cache=False):
+def _slstm_full(cfg, h, p, *, state=None, make_cache=False):
     x = apply_norm(cfg.norm, h, p["ln"])
     y, new_state = rec_lib.slstm_seq(x, p, cfg.n_heads, state=state)
     y = rec_lib.groupnorm_heads(y, p["gn"], cfg.n_heads)
@@ -514,42 +474,12 @@ def _slstm_full(cfg, rc, h, p, *, state=None, make_cache=False):
 # Full-sequence forward (train / prefill)
 # ===========================================================================
 
-def _scan_blocks(cfg, rc, carry, params, body):
-    """scan over stacked blocks with optional double-remat grouping.
-
-    ``body(carry, layer_params) -> carry``.  The first carry leaf is the
-    residual stream and gets a sharding constraint between layers.
-    """
-    blocks = params["blocks"]
-    L = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-
+def _scan_blocks(carry, params, body):
+    """scan over the stacked blocks; ``body(carry, layer_params) -> carry``."""
     def layer(c, p):
-        c = body(c, p)
-        if isinstance(c, tuple):
-            c = (shard_activation(c[0], "residual", rc),) + c[1:]
-        else:
-            c = shard_activation(c, "residual", rc)
-        return c, None
+        return body(c, p), None
 
-    G = rc.remat_groups
-    if rc.remat_policy == "dots":
-        layer = jax.checkpoint(
-            layer,
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-    elif rc.remat_policy != "none":
-        layer = jax.checkpoint(layer)   # per-layer full remat
-    if G > 1 and L % G == 0:            # + double remat over layer groups
-        grouped = jax.tree_util.tree_map(
-            lambda a: a.reshape((G, L // G) + a.shape[1:]), blocks)
-
-        def group(c, gp):
-            c, _ = jax.lax.scan(layer, c, gp, unroll=rc.cost_probe)
-            return c, None
-
-        group = jax.checkpoint(group)
-        carry, _ = jax.lax.scan(group, carry, grouped, unroll=rc.cost_probe)
-    else:
-        carry, _ = jax.lax.scan(layer, carry, blocks, unroll=rc.cost_probe)
+    carry, _ = jax.lax.scan(layer, carry, params["blocks"])
     return carry
 
 
@@ -559,71 +489,68 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
     h = embed_inputs(cfg, params, batch, rc)
     B, S = h.shape[0], h.shape[1]
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    h = shard_activation(h, "residual", rc)
     metrics: Dict[str, Any] = {}
     fam = cfg.family
 
     if fam in ("dense", "vlm", "audio"):
         def body(h, p):
-            h, _ = _attn_full(cfg, rc, h, p["attn"], positions)
-            return _mlp_full(cfg, rc, h, p["mlp"])
-        h = _scan_blocks(cfg, rc, h, params, body)
+            h, _ = _attn_full(cfg, h, p["attn"], positions)
+            return _mlp_full(cfg, h, p["mlp"])
+        h = _scan_blocks(h, params, body)
     elif fam == "moe":
         def body(carry, p):
             h, aux = carry
-            h, _ = _attn_full(cfg, rc, h, p["attn_a"], positions)
-            h = _mlp_full(cfg, rc, h, p["mlp"])
-            h, _ = _attn_full(cfg, rc, h, p["attn_b"], positions)
-            h, aux = _moe_full(cfg, rc, h, p["moe"], aux)
+            h, _ = _attn_full(cfg, h, p["attn_a"], positions)
+            h = _mlp_full(cfg, h, p["mlp"])
+            h, _ = _attn_full(cfg, h, p["attn_b"], positions)
+            h, aux = _moe_full(cfg, h, p["moe"], aux)
             return (h, aux)
         aux0 = {k: jnp.zeros((), jnp.float32) for k in MOE_METRIC_KEYS}
-        h, aux = _scan_blocks(cfg, rc, (h, aux0), params, body)
+        h, aux = _scan_blocks((h, aux0), params, body)
         metrics.update(aux)
     elif fam == "mla_moe":
         def body(carry, p):
             h, aux = carry
-            h, _ = _mla_full(cfg, rc, h, p["attn"], positions)
-            h, aux = _moe_full(cfg, rc, h, p["moe"], aux)
+            h, _ = _mla_full(cfg, h, p["attn"], positions)
+            h, aux = _moe_full(cfg, h, p["moe"], aux)
             return (h, aux)
         aux0 = {k: jnp.zeros((), jnp.float32) for k in MOE_METRIC_KEYS}
-        h, aux = _scan_blocks(cfg, rc, (h, aux0), params, body)
+        h, aux = _scan_blocks((h, aux0), params, body)
         metrics.update(aux)
     elif fam == "hybrid":
         w = cfg.rglru.window
 
         def body(h, p):
-            h, _ = _rglru_full(cfg, rc, h, p["rec0"])
-            h = _mlp_full(cfg, rc, h, p["mlp0"], act="gelu")
-            h, _ = _rglru_full(cfg, rc, h, p["rec1"])
-            h = _mlp_full(cfg, rc, h, p["mlp1"], act="gelu")
-            h, _ = _attn_full(cfg, rc, h, p["attn"], positions, window=w)
-            return _mlp_full(cfg, rc, h, p["mlp2"], act="gelu")
-        h = _scan_blocks(cfg, rc, h, params, body)
+            h, _ = _rglru_full(cfg, h, p["rec0"])
+            h = _mlp_full(cfg, h, p["mlp0"], act="gelu")
+            h, _ = _rglru_full(cfg, h, p["rec1"])
+            h = _mlp_full(cfg, h, p["mlp1"], act="gelu")
+            h, _ = _attn_full(cfg, h, p["attn"], positions, window=w)
+            return _mlp_full(cfg, h, p["mlp2"], act="gelu")
+        h = _scan_blocks(h, params, body)
         if params.get("tail"):
             tail = params["tail"]
             n_tail = jax.tree_util.tree_leaves(tail)[0].shape[0]
             for i in range(n_tail):
                 tp = jax.tree_util.tree_map(lambda a: a[i], tail)
-                h, _ = _rglru_full(cfg, rc, h, tp["rec"])
-                h = _mlp_full(cfg, rc, h, tp["mlp"], act="gelu")
+                h, _ = _rglru_full(cfg, h, tp["rec"])
+                h = _mlp_full(cfg, h, tp["mlp"], act="gelu")
     elif fam == "xlstm":
         n_m = cfg.xlstm.slstm_every - 1
 
         def body(h, p):
             for i in range(n_m):
                 mp = jax.tree_util.tree_map(lambda a: a[i], p["m"])
-                h, _ = _mlstm_full(cfg, rc, h, mp)
-            h, _ = _slstm_full(cfg, rc, h, p["s"])
+                h, _ = _mlstm_full(cfg, h, mp)
+            h, _ = _slstm_full(cfg, h, p["s"])
             return h
-        h = _scan_blocks(cfg, rc, h, params, body)
+        h = _scan_blocks(h, params, body)
     else:
         raise ValueError(fam)
 
     if return_hidden:
         return h, metrics
-    logits = lm_logits(cfg, params, h, rc)
-    logits = shard_activation(logits, "logits", rc)
-    return logits, metrics
+    return lm_logits(cfg, params, h, rc), metrics
 
 
 # ===========================================================================
@@ -702,57 +629,54 @@ def prefill(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
     h = embed_inputs(cfg, params, batch, rc)
     B, S = h.shape[0], h.shape[1]
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    h = shard_activation(h, "residual", rc)
     fam = cfg.family
     blocks = params["blocks"]
     metrics: Dict[str, Any] = {}
 
     if fam in ("dense", "vlm", "audio"):
         def body(h, p):
-            h, (k, v) = _attn_full(cfg, rc, h, p["attn"], positions,
+            h, (k, v) = _attn_full(cfg, h, p["attn"], positions,
                                    make_cache=True)
-            h = _mlp_full(cfg, rc, h, p["mlp"])
-            return shard_activation(h, "residual", rc), {"ck": k, "cv": v}
-        h, cache = jax.lax.scan(body, h, blocks, unroll=rc.cost_probe)
+            h = _mlp_full(cfg, h, p["mlp"])
+            return h, {"ck": k, "cv": v}
+        h, cache = jax.lax.scan(body, h, blocks)
     elif fam == "moe":
         def body(h, p):
-            h, (ka, va) = _attn_full(cfg, rc, h, p["attn_a"], positions,
+            h, (ka, va) = _attn_full(cfg, h, p["attn_a"], positions,
                                      make_cache=True)
-            h = _mlp_full(cfg, rc, h, p["mlp"])
-            h, (kb, vb) = _attn_full(cfg, rc, h, p["attn_b"], positions,
+            h = _mlp_full(cfg, h, p["mlp"])
+            h, (kb, vb) = _attn_full(cfg, h, p["attn_b"], positions,
                                      make_cache=True)
             h = _moe_nometrics(cfg, h, p["moe"])
-            return shard_activation(h, "residual", rc), \
-                {"cka": ka, "cva": va, "ckb": kb, "cvb": vb}
-        h, cache = jax.lax.scan(body, h, blocks, unroll=rc.cost_probe)
+            return h, {"cka": ka, "cva": va, "ckb": kb, "cvb": vb}
+        h, cache = jax.lax.scan(body, h, blocks)
     elif fam == "mla_moe":
         def body(h, p):
-            h, (c, kr) = _mla_full(cfg, rc, h, p["attn"], positions,
+            h, (c, kr) = _mla_full(cfg, h, p["attn"], positions,
                                    make_cache=True)
             h = _moe_nometrics(cfg, h, p["moe"])
-            return shard_activation(h, "residual", rc), {"cc": c, "ckr": kr}
-        h, cache = jax.lax.scan(body, h, blocks, unroll=rc.cost_probe)
+            return h, {"cc": c, "ckr": kr}
+        h, cache = jax.lax.scan(body, h, blocks)
     elif fam == "hybrid":
         w = cfg.rglru.window
 
         def body(h, p):
-            h, (h0, cv0) = _rglru_full(cfg, rc, h, p["rec0"], make_cache=True)
-            h = _mlp_full(cfg, rc, h, p["mlp0"], act="gelu")
-            h, (h1, cv1) = _rglru_full(cfg, rc, h, p["rec1"], make_cache=True)
-            h = _mlp_full(cfg, rc, h, p["mlp1"], act="gelu")
-            h, (kc, vc) = _attn_full(cfg, rc, h, p["attn"], positions,
+            h, (h0, cv0) = _rglru_full(cfg, h, p["rec0"], make_cache=True)
+            h = _mlp_full(cfg, h, p["mlp0"], act="gelu")
+            h, (h1, cv1) = _rglru_full(cfg, h, p["rec1"], make_cache=True)
+            h = _mlp_full(cfg, h, p["mlp1"], act="gelu")
+            h, (kc, vc) = _attn_full(cfg, h, p["attn"], positions,
                                      window=w, make_cache=True)
-            h = _mlp_full(cfg, rc, h, p["mlp2"], act="gelu")
-            return shard_activation(h, "residual", rc), \
-                {"rh0": h0, "rconv0": cv0, "rh1": h1, "rconv1": cv1,
-                 "wk": kc, "wv": vc}
-        h, cache = jax.lax.scan(body, h, blocks, unroll=rc.cost_probe)
+            h = _mlp_full(cfg, h, p["mlp2"], act="gelu")
+            return h, {"rh0": h0, "rconv0": cv0, "rh1": h1, "rconv1": cv1,
+                       "wk": kc, "wv": vc}
+        h, cache = jax.lax.scan(body, h, blocks)
         if params.get("tail"):
             def tbody(h, p):
-                h, (hs, cv) = _rglru_full(cfg, rc, h, p["rec"], make_cache=True)
-                h = _mlp_full(cfg, rc, h, p["mlp"], act="gelu")
+                h, (hs, cv) = _rglru_full(cfg, h, p["rec"], make_cache=True)
+                h = _mlp_full(cfg, h, p["mlp"], act="gelu")
                 return h, {"rh": hs, "rconv": cv}
-            h, tcache = jax.lax.scan(tbody, h, params["tail"], unroll=rc.cost_probe)
+            h, tcache = jax.lax.scan(tbody, h, params["tail"])
             cache["tail"] = tcache
     elif fam == "xlstm":
         n_m = cfg.xlstm.slstm_every - 1
@@ -761,15 +685,15 @@ def prefill(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
             mC, mn, mm, mcv = [], [], [], []
             for i in range(n_m):
                 mp = jax.tree_util.tree_map(lambda a: a[i], p["m"])
-                h, st = _mlstm_full(cfg, rc, h, mp, make_cache=True)
+                h, st = _mlstm_full(cfg, h, mp, make_cache=True)
                 (C, n, m), conv = st
                 mC.append(C); mn.append(n); mm.append(m); mcv.append(conv)
-            h, s_st = _slstm_full(cfg, rc, h, p["s"], make_cache=True)
+            h, s_st = _slstm_full(cfg, h, p["s"], make_cache=True)
             sc, sn, sh, sm = s_st
             return h, {"mC": jnp.stack(mC), "mn": jnp.stack(mn),
                        "mm": jnp.stack(mm), "mconv": jnp.stack(mcv),
                        "sc": sc, "sn": sn, "sh": sh, "sm": sm}
-        h, cache = jax.lax.scan(body, h, blocks, unroll=rc.cost_probe)
+        h, cache = jax.lax.scan(body, h, blocks)
     else:
         raise ValueError(fam)
 
@@ -786,32 +710,24 @@ def prefill(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
 
 # --- per-family decode bodies ----------------------------------------------
 
-def _kv_write(c, row, i, pos, rc):
+def _kv_write(c, row, i, pos):
     """Write ``row`` (B, Hkv, Dh) into the stacked cache ``c`` (L, B, S,
     Hkv, Dh) at layer ``i``, position ``pos``: one row-sized update,
-    made in place when the caller donates the cache.  Where ``c`` is
-    sharded along S (``runtime.sharding.kv_seq_sharded``), an update at
-    a traced position on that dim makes GSPMD gather the cache, so there
-    the row goes in as a select over the layer's slice, which partitions
-    cleanly (``rc.dus_cache_update`` measures the update there too)."""
-    if kv_seq_sharded(c.shape) and not rc.dus_cache_update:
-        layer = jax.lax.dynamic_index_in_dim(c, i, keepdims=False)
-        return jax.lax.dynamic_update_index_in_dim(
-            c, attn_lib.cache_update(layer, row, pos), i, axis=0)
+    made in place when the caller donates the cache."""
     return jax.lax.dynamic_update_slice(
         c, row[None, :, None].astype(c.dtype), (i, 0, pos, 0, 0))
 
 
 @jax.named_scope("attn")
-def _attn_decode(cfg, rc, h, p, ck, cv, i, pos, positions):
+def _attn_decode(cfg, h, p, ck, cv, i, pos, positions):
     """Layer ``i``'s attention for one new token.  ``ck``/``cv`` are the
     whole stacked (L, B, S, Hkv, Dh) caches the layer scan carries: the
     new K/V row is written into them at (i, :, pos) and attention reads
     their layer ``i``; no layer's slice is copied out and written back."""
     x = apply_norm(cfg.norm, h, p["ln"])
     q, k, v = attn_lib.gqa_project_qkv(x, p, cfg, positions)
-    ck = _kv_write(ck, k[:, 0], i, pos, rc)
-    cv = _kv_write(cv, v[:, 0], i, pos, rc)
+    ck = _kv_write(ck, k[:, 0], i, pos)
+    cv = _kv_write(cv, v[:, 0], i, pos)
     o = attn_lib.decode_attention(
         q[:, 0], jax.lax.dynamic_index_in_dim(ck, i, keepdims=False),
         jax.lax.dynamic_index_in_dim(cv, i, keepdims=False), pos)
@@ -821,23 +737,22 @@ def _attn_decode(cfg, rc, h, p, ck, cv, i, pos, positions):
 
 
 @jax.named_scope("attn")
-def _window_attn_decode(cfg, rc, h, p, ck, cv, pos, positions):
+def _window_attn_decode(cfg, h, p, ck, cv, pos, positions):
     """Local attention for one new token over one layer's ring cache
     ``ck``/``cv`` (B, W, Hkv, Dh): the row goes to slot ``pos % W``."""
     x = apply_norm(cfg.norm, h, p["ln"])
     q, k, v = attn_lib.gqa_project_qkv(x, p, cfg, positions)
-    dus = rc.dus_cache_update
     W = ck.shape[1]
     slot = pos % W
-    ck = attn_lib.cache_update(ck, k[:, 0], slot, use_dus=dus)
-    cv = attn_lib.cache_update(cv, v[:, 0], slot, use_dus=dus)
+    ck = attn_lib.cache_update(ck, k[:, 0], slot)
+    cv = attn_lib.cache_update(cv, v[:, 0], slot)
     o = attn_lib.decode_attention(q[:, 0], ck, cv, jnp.minimum(pos, W - 1))
     o = o.reshape(o.shape[0], 1, -1)
     h = h + jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(o.dtype))
     return h, ck, cv
 
 
-def _rglru_decode(cfg, rc, h, p, rh, rconv):
+def _rglru_decode(cfg, h, p, rh, rconv):
     x = apply_norm(cfg.norm, h, p["ln"])
     y = jax.nn.gelu(jnp.einsum("bsd,dr->bsr", x, p["w_y"].astype(x.dtype)))
     xb = jnp.einsum("bsd,dr->bsr", x, p["w_xb"].astype(x.dtype))
@@ -847,7 +762,7 @@ def _rglru_decode(cfg, rc, h, p, rh, rconv):
     return h + out[:, None], rh, rconv
 
 
-def _mlstm_decode(cfg, rc, h, p, state, conv):
+def _mlstm_decode(cfg, h, p, state, conv):
     x = apply_norm(cfg.norm, h, p["ln"])
     H = cfg.n_heads
     up = jnp.einsum("bsd,de->bse", x, p["w_up"].astype(x.dtype))
@@ -871,7 +786,7 @@ def _mlstm_decode(cfg, rc, h, p, state, conv):
     return h + out[:, None], state, conv
 
 
-def _scan_layers(body, h, blocks, cache, keys, rc):
+def _scan_layers(body, h, blocks, cache, keys):
     """Layer scan with the decode caches ``keys`` as a *carry* (not
     xs/ys), whole, with the layer's index.
 
@@ -886,14 +801,13 @@ def _scan_layers(body, h, blocks, cache, keys, rc):
 
     (h, caches, _), _ = jax.lax.scan(
         step, (h, {k: cache[k] for k in keys}, jnp.zeros((), jnp.int32)),
-        blocks, unroll=rc.cost_probe)
+        blocks)
     return h, caches
 
 
-def _scan_layers_carry(body_kv, h, blocks, cache, keys, rc):
+def _scan_layers_carry(body_kv, h, blocks, cache, keys):
     """``_scan_layers`` for bodies that take one layer's slice of each
-    cache and return it whole; the per-layer index / update on the
-    (unsharded) leading layer dim partitions cleanly.
+    cache and return it whole.
     body_kv(h, p, layer_cache) -> (h, new_layer_cache).
     """
     def body(h, p, caches, i):
@@ -904,7 +818,7 @@ def _scan_layers_carry(body_kv, h, blocks, cache, keys, rc):
             caches[k], newl[k].astype(caches[k].dtype), i, axis=0)
             for k in caches}
 
-    return _scan_layers(body, h, blocks, cache, keys, rc)
+    return _scan_layers(body, h, blocks, cache, keys)
 
 
 def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
@@ -923,25 +837,25 @@ def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
 
     if fam in ("dense", "vlm", "audio"):
         def body(h, p, c, i):
-            h, ck, cv = _attn_decode(cfg, rc, h, p["attn"], c["ck"], c["cv"],
+            h, ck, cv = _attn_decode(cfg, h, p["attn"], c["ck"], c["cv"],
                                      i, pos, positions)
-            h = _mlp_full(cfg, rc, h, p["mlp"])
+            h = _mlp_full(cfg, h, p["mlp"])
             return h, {"ck": ck, "cv": cv}
-        h, kv = _scan_layers(body, h, blocks, cache, ("ck", "cv"), rc)
+        h, kv = _scan_layers(body, h, blocks, cache, ("ck", "cv"))
         new_cache.update(kv)
     elif fam == "moe":
         def body(h, p, c, i):
-            h, cka, cva = _attn_decode(cfg, rc, h, p["attn_a"], c["cka"],
+            h, cka, cva = _attn_decode(cfg, h, p["attn_a"], c["cka"],
                                        c["cva"], i, pos, positions)
-            h = _mlp_full(cfg, rc, h, p["mlp"])
-            h, ckb, cvb = _attn_decode(cfg, rc, h, p["attn_b"], c["ckb"],
+            h = _mlp_full(cfg, h, p["mlp"])
+            h, ckb, cvb = _attn_decode(cfg, h, p["attn_b"], c["ckb"],
                                        c["cvb"], i, pos, positions)
             x = apply_norm(cfg.norm, h, p["moe"]["ln"])
             y, _ = ffn_lib.moe_apply(x.reshape(1, B, -1), p["moe"], cfg)
             h = h + y.reshape(B, 1, -1)
             return h, {"cka": cka, "cva": cva, "ckb": ckb, "cvb": cvb}
         h, kv = _scan_layers(body, h, blocks, cache,
-                             ("cka", "cva", "ckb", "cvb"), rc)
+                             ("cka", "cva", "ckb", "cvb"))
         new_cache.update(kv)
     elif fam == "mla_moe":
         def body(h, p, c):
@@ -953,33 +867,33 @@ def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
             y, _ = ffn_lib.moe_apply(x.reshape(1, B, -1), p["moe"], cfg)
             h = h + y.reshape(B, 1, -1)
             return h, {"cc": cc, "ckr": ckr}
-        h, kv = _scan_layers_carry(body, h, blocks, cache, ("cc", "ckr"), rc)
+        h, kv = _scan_layers_carry(body, h, blocks, cache, ("cc", "ckr"))
         new_cache.update(kv)
     elif fam == "hybrid":
         def body(h, p, c):
-            h, rh0, rcv0 = _rglru_decode(cfg, rc, h, p["rec0"], c["rh0"],
+            h, rh0, rcv0 = _rglru_decode(cfg, h, p["rec0"], c["rh0"],
                                          c["rconv0"])
-            h = _mlp_full(cfg, rc, h, p["mlp0"], act="gelu")
-            h, rh1, rcv1 = _rglru_decode(cfg, rc, h, p["rec1"], c["rh1"],
+            h = _mlp_full(cfg, h, p["mlp0"], act="gelu")
+            h, rh1, rcv1 = _rglru_decode(cfg, h, p["rec1"], c["rh1"],
                                          c["rconv1"])
-            h = _mlp_full(cfg, rc, h, p["mlp1"], act="gelu")
-            h, wk, wv = _window_attn_decode(cfg, rc, h, p["attn"], c["wk"],
+            h = _mlp_full(cfg, h, p["mlp1"], act="gelu")
+            h, wk, wv = _window_attn_decode(cfg, h, p["attn"], c["wk"],
                                             c["wv"], pos, positions)
-            h = _mlp_full(cfg, rc, h, p["mlp2"], act="gelu")
+            h = _mlp_full(cfg, h, p["mlp2"], act="gelu")
             return h, {"rh0": rh0, "rconv0": rcv0, "rh1": rh1, "rconv1": rcv1,
                        "wk": wk, "wv": wv}
         h, kv = _scan_layers_carry(body, h, blocks, cache,
                                    ("rh0", "rconv0", "rh1", "rconv1",
-                                    "wk", "wv"), rc)
+                                    "wk", "wv"))
         new_cache.update(kv)
         if params.get("tail"):
             def tbody(h, p, c):
-                h, rh, rcv = _rglru_decode(cfg, rc, h, p["rec"], c["rh"],
+                h, rh, rcv = _rglru_decode(cfg, h, p["rec"], c["rh"],
                                            c["rconv"])
-                h = _mlp_full(cfg, rc, h, p["mlp"], act="gelu")
+                h = _mlp_full(cfg, h, p["mlp"], act="gelu")
                 return h, {"rh": rh, "rconv": rcv}
             h, tkv = _scan_layers_carry(tbody, h, params["tail"],
-                                        cache["tail"], ("rh", "rconv"), rc)
+                                        cache["tail"], ("rh", "rconv"))
             new_cache["tail"] = tkv
     elif fam == "xlstm":
         n_m = cfg.xlstm.slstm_every - 1
@@ -989,7 +903,7 @@ def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
             for i in range(n_m):
                 mp = jax.tree_util.tree_map(lambda a: a[i], p["m"])
                 st = (c["mC"][i], c["mn"][i], c["mm"][i])
-                h, st, cv = _mlstm_decode(cfg, rc, h, mp, st, c["mconv"][i])
+                h, st, cv = _mlstm_decode(cfg, h, mp, st, c["mconv"][i])
                 mC.append(st[0]); mn.append(st[1]); mm.append(st[2])
                 mcv.append(cv)
             x = apply_norm(cfg.norm, h, p["s"]["ln"])
@@ -1006,7 +920,7 @@ def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
                        "sm": s_st[3]}
         h, kv = _scan_layers_carry(body, h, blocks, cache,
                                    ("mC", "mn", "mm", "mconv",
-                                    "sc", "sn", "sh", "sm"), rc)
+                                    "sc", "sn", "sh", "sm"))
         new_cache.update(kv)
     else:
         raise ValueError(fam)
@@ -1017,17 +931,16 @@ def decode_step(cfg: ArchConfig, params: Params, tokens, cache,
 
 
 LOSS_CHUNK = 512
+Z_LOSS_COEF = 1e-4
 
 
-def chunked_xent(cfg: ArchConfig, params: Params, h, labels,
-                 rc: RuntimeConfig):
+def chunked_xent(cfg: ArchConfig, params: Params, h, labels):
     """Cross-entropy without materializing full-sequence fp32 logits.
 
     Scans S in chunks; each chunk projects h -> logits and reduces to sums;
     jax.checkpoint makes the backward recompute chunk logits instead of
     saving them (decisive at 150k-200k vocab: full fp32 logits are GBs).
     """
-    from repro.models.common import softmax_xent_sums
     w = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     B, S = h.shape[0], h.shape[1]
     chunk = LOSS_CHUNK if (S % LOSS_CHUNK == 0) else S
@@ -1039,15 +952,14 @@ def chunked_xent(cfg: ArchConfig, params: Params, h, labels,
         if cfg.family == "audio":
             logits = logits.reshape(logits.shape[:-1]
                                     + (cfg.n_codebooks, cfg.vocab))
-        logits = shard_activation(logits, "logits", rc)
-        t, n_, nv = softmax_xent_sums(logits, lc, z_loss_coef=rc.z_loss)
+        t, n_, nv = softmax_xent_sums(logits, lc, z_loss_coef=Z_LOSS_COEF)
         return (carry[0] + t, carry[1] + n_, carry[2] + nv), None
 
     hcs = jnp.moveaxis(h.reshape(B, nc, chunk, -1), 1, 0)
     lcs = jnp.moveaxis(labels.reshape((B, nc, chunk) + labels.shape[2:]), 1, 0)
     z = jnp.zeros((), jnp.float32)
     (tot, nll, n), _ = jax.lax.scan(jax.checkpoint(body), (z, z, z),
-                                    (hcs, lcs), unroll=rc.cost_probe)
+                                    (hcs, lcs))
     n = jnp.maximum(n, 1.0)
     return tot / n, {"nll": nll / n, "ntokens": n}
 
@@ -1062,7 +974,7 @@ def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
         pad = jnp.full(labels.shape[:1] + (nf,), -1, labels.dtype)
         labels = jnp.concatenate([pad, labels], axis=1)
     h = apply_norm(cfg.norm, h, params["out_norm"])
-    loss, lm_metrics = chunked_xent(cfg, params, h, labels, rc)
+    loss, lm_metrics = chunked_xent(cfg, params, h, labels)
     metrics.update(lm_metrics)
     for k in ("moe_aux", "moe_z"):
         if k in metrics:

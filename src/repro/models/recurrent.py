@@ -162,8 +162,7 @@ def mlstm_final_state(q, k, v, log_i, log_f, state=None):
     return _chunk_update(k, v, log_i.astype(jnp.float32), log_f, F, state)
 
 
-def mlstm_chunkwise(q, k, v, log_i, log_f, *, chunk: int, state=None,
-                    unroll: bool = False):
+def mlstm_chunkwise(q, k, v, log_i, log_f, *, chunk: int, state=None):
     """Chunkwise-parallel mLSTM: O(S*chunk) intra + O(S/chunk) recurrence.
 
     q,k,v (B,H,S,dh); log_i/log_f (B,H,S).  Returns (h, final_state).
@@ -209,7 +208,7 @@ def mlstm_chunkwise(q, k, v, log_i, log_f, *, chunk: int, state=None,
         return new_state, h
 
     final_state, hs = jax.lax.scan(jax.checkpoint(step), state,
-                                   (qs, ks, vs, lis, lfs), unroll=unroll)
+                                   (qs, ks, vs, lis, lfs))
     h = hs.transpose(1, 2, 0, 3, 4).reshape(B, H, S, dv)
     return h, final_state
 

@@ -1,13 +1,10 @@
-"""train_step / serve_step factories with DP(+pod) x FSDP x TP sharding.
+"""The train_step factory.
 
 ``make_train_step`` builds the jit-able step:
   grads = grad(loss);  optional microbatch accumulation (lax.scan);
-  optional int8 cross-pod gradient compression; AdamW update.
+  AdamW update.
 """
 from __future__ import annotations
-
-import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -63,19 +60,6 @@ def make_train_step(cfg: ArchConfig, rc: RuntimeConfig = DEFAULT_RC,
         return params, opt_state, metrics
 
     return train_step
-
-
-def make_prefill_step(cfg: ArchConfig, rc: RuntimeConfig = DEFAULT_RC,
-                      max_len: Optional[int] = None):
-    def prefill_step(params, batch):
-        return lm.prefill(cfg, params, batch, rc, max_len=max_len)
-    return prefill_step
-
-
-def make_decode_step(cfg: ArchConfig, rc: RuntimeConfig = DEFAULT_RC):
-    def serve_step(params, tokens, cache):
-        return lm.decode_step(cfg, params, tokens, cache, rc)
-    return serve_step
 
 
 def init_train_state(cfg: ArchConfig, key, rc: RuntimeConfig = DEFAULT_RC,

@@ -11,9 +11,9 @@ for p in (str(_ROOT / "src"), str(_ROOT)):
 
 # the suite runs on a >= 4-way logical CPU device pool so the sharded jit
 # gates (tests/test_device_sharding.py, tests/harness.py cases) execute
-# in-process; any inherited flag — e.g. the dry-run's 512-device one (see
-# src/repro/launch/dryrun.py) — is dropped first, then the pool is forced
-# before jax initializes.  REPRO_DEVICES (the CI device matrix) can only
+# in-process; any inherited XLA_FLAGS (a forced device count of another
+# size, say) is dropped first, then the pool is forced before jax
+# initializes.  REPRO_DEVICES (the CI device matrix) can only
 # widen the pool; it is deliberately NOT defaulted here, so the engine's
 # device *default* stays 1 and sharding in tests is always explicit.
 os.environ.pop("XLA_FLAGS", None)

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCHS, get_config, SHAPES_BY_NAME, supports_shape
+from repro.configs import ARCHS, get_config
 from repro.data import batch_for_arch
 from repro.models import lm
 from repro.models.common import CPU_RC
@@ -53,10 +53,3 @@ def test_full_config_metadata(arch):
     cfg = ARCHS[arch]
     assert cfg.param_count() > 0
     assert cfg.active_param_count() <= cfg.param_count()
-    for s in ("train_4k", "prefill_32k", "decode_32k", "long_500k"):
-        shape = SHAPES_BY_NAME[s]
-        ok = supports_shape(cfg, shape)
-        if s == "long_500k":
-            assert ok == (cfg.family in ("hybrid", "xlstm"))
-        else:
-            assert ok

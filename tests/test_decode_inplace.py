@@ -22,7 +22,6 @@ from repro.core.task import Crit
 from repro.models import attention as attn_lib
 from repro.models import lm
 from repro.models.common import CPU_RC, DEFAULT_RC, apply_norm
-from repro.runtime import sharding as shlib
 
 S, MAX_LEN, STEPS = 8, 16, 6
 HEAD_MAJOR = (0, 1, 3, 2, 4)
@@ -65,7 +64,7 @@ def _layer_slice_decode(cfg, params, tokens, cache, rc):
         o = attn_lib.decode_attention(q[:, 0], lk, lv, pos)
         o = o.reshape(o.shape[0], 1, -1)
         h = h + jnp.einsum("bsh,hd->bsd", o, a["wo"].astype(o.dtype))
-        h = lm._mlp_full(cfg, rc, h, p["mlp"])
+        h = lm._mlp_full(cfg, h, p["mlp"])
         ck = jax.lax.dynamic_update_index_in_dim(ck, lk, i, axis=0)
         cv = jax.lax.dynamic_update_index_in_dim(cv, lv, i, axis=0)
         return (h, ck, cv, i + 1), None
@@ -201,40 +200,3 @@ def test_no_cache_is_passed_to_the_decode_twice(order):
     assert lo.saves == 1 and lo.done and hi.done
     assert len({id(c) for c in given}) == len(given) > 8
     assert all(c["ck"].shape == shape and default_layout(c) for c in given)
-
-
-def test_only_an_s_sharded_cache_keeps_the_layer_wide_select():
-    """Where the cache's S dim is split over a mesh axis the row goes in
-    as a select over the layer's slice (GSPMD partitions it cleanly); on
-    one device, and where S does not divide, it is one row update."""
-    from jax.sharding import Mesh
-    cfg = _cfg("tinyllama-1.1b")
-    params = jax.eval_shape(
-        lambda: lm.init_params(cfg, jax.random.PRNGKey(0), CPU_RC))
-    cache = jax.eval_shape(lambda: lm.init_cache(cfg, 1, MAX_LEN, CPU_RC))
-    tok = jax.ShapeDtypeStruct((1,), jnp.int32)
-    layer = cache["ck"].shape[1:]
-
-    def count(jaxpr):
-        n = sum(e.primitive.name == "select_n"
-                and e.outvars[0].aval.shape == layer for e in jaxpr.eqns)
-        for e in jaxpr.eqns:
-            for v in e.params.values():
-                sub = getattr(v, "jaxpr", v)
-                if hasattr(sub, "eqns"):
-                    n += count(sub)
-        return n
-
-    def layer_selects():
-        return count(jax.make_jaxpr(lambda p, t, c: lm.decode_step(
-            cfg, p, t, c, CPU_RC))(params, tok, cache).jaxpr)
-
-    assert layer_selects() == 0
-    devs = np.asarray(jax.devices()[:4]).reshape(1, 4)
-    rules = shlib.AxisRules(Mesh(devs, ("data", "model")))
-    with shlib.axis_rules(rules):
-        assert shlib.kv_seq_sharded(cache["ck"].shape)
-        assert not shlib.kv_seq_sharded(cache["ck"].shape[:2] + (6,)
-                                        + cache["ck"].shape[3:])
-        assert layer_selects() == 2
-    assert not shlib.kv_seq_sharded(cache["ck"].shape)

@@ -135,7 +135,7 @@ def test_chunked_xent_matches_full():
     h, _ = lm.forward(cfg, params, {"tokens": toks}, CPU_RC,
                       return_hidden=True)
     hn = apply_norm(cfg.norm, h, params["out_norm"])
-    l1, _ = lm.chunked_xent(cfg, params, hn, toks, CPU_RC)
+    l1, _ = lm.chunked_xent(cfg, params, hn, toks)
     logits, _ = lm.forward(cfg, params, {"tokens": toks}, CPU_RC)
-    l2, _ = softmax_xent(logits, toks, z_loss_coef=CPU_RC.z_loss)
+    l2, _ = softmax_xent(logits, toks, z_loss_coef=lm.Z_LOSS_COEF)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
